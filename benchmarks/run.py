@@ -45,6 +45,10 @@ def main(argv=None) -> None:
         json_dir = pathlib.Path(args.json)
         json_dir.mkdir(parents=True, exist_ok=True)
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()   # every suite runs in this process: one cache
+
     from benchmarks import (chaos_bench, decode_bench, fleet_bench,
                             gateway_bench, kernel_bench, licensing_ladder,
                             paging_bench, prefill_bench, prefix_bench,

@@ -12,11 +12,13 @@ with no contiguous copy of the sequence ever existing.
 
 Layout: one query token per sequence (decode), GQA handled in-kernel by
 reshaping the query to (kv_heads, group, head_dim) and unrolling the
-(static, small) kv-head loop into 2-D MXU dots.  Online-softmax running
-stats (m, l) persist in output refs across the sequential innermost
-block-table axis, exactly like ``flash_attention.py``; positions at or
-beyond a sequence's ``context_lens`` (including anything read through
-null/pad table entries) are masked inert.
+(static, small) kv-head loop into 2-D MXU dots.  The output block
+accumulates across the sequential innermost block-table axis; the
+online-softmax running stats (m, l) live in (H, 1) VMEM scratch, since a
+(1, H) block over a (B, H) output would break the TPU tiling rule (the
+last two block dims divisible by (8, 128) or equal to the array's).
+Positions at or beyond a sequence's ``context_lens`` (including anything
+read through null/pad table entries) are masked inert.
 """
 from __future__ import annotations
 
@@ -55,8 +57,8 @@ def _kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         qg = q.reshape(kv_heads, groups, hd)
 
         k_pos = t * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1)[0]
-        valid = k_pos < ctx                               # (bs,)
+            jnp.int32, (1, block_size), 1)
+        valid = k_pos < ctx                               # (1, bs)
 
         # per-kv-head 2-D dots (KH is static and small -> unrolled)
         s = jnp.stack([
@@ -64,12 +66,12 @@ def _kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                                 preferred_element_type=jnp.float32)
             for kh in range(kv_heads)
         ], 0).reshape(h, block_size)
-        s = jnp.where(valid[None, :], s, NEG_INF)
+        s = jnp.where(valid, s, NEG_INF)
 
-        m_prev = m_ref[0]
-        l_prev = l_ref[0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.where(valid[None, :], jnp.exp(s - m_new[:, None]), 0.0)
+        m_prev = m_ref[...]                               # (H, 1)
+        l_prev = l_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
         alpha = jnp.where(m_prev == NEG_INF, 0.0, alpha)
         pg = p.reshape(kv_heads, groups, block_size)
@@ -78,13 +80,13 @@ def _kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                                 preferred_element_type=jnp.float32)
             for kh in range(kv_heads)
         ], 0).reshape(h, hd)
-        o_ref[0] = o_ref[0] * alpha[:, None] + acc
-        m_ref[0] = m_new
-        l_ref[0] = l_prev * alpha + jnp.sum(p, axis=-1)
+        o_ref[0] = o_ref[0] * alpha + acc
+        m_ref[...] = m_new
+        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
 
     @pl.when(t == n_blocks - 1)
     def _normalize():
-        o_ref[0] = o_ref[0] / jnp.maximum(l_ref[0], 1e-20)[:, None]
+        o_ref[0] = o_ref[0] / jnp.maximum(l_ref[...], 1e-20)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -127,24 +129,17 @@ def paged_attention(
             pl.BlockSpec((1, bs, kh, hd),
                          lambda b, t, tab, ln: (tab[b, t], 0, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, h, hd), lambda b, t, tab, ln: (b, 0, 0)),
-            pl.BlockSpec((1, h), lambda b, t, tab, ln: (b, 0)),
-            pl.BlockSpec((1, h), lambda b, t, tab, ln: (b, 0)),
-        ],
+        out_specs=pl.BlockSpec((1, h, hd), lambda b, t, tab, ln: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),   # running max
+                        pltpu.VMEM((h, 1), jnp.float32)],  # running sum
     )
-    out, _, _ = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, h), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(context_lens, jnp.int32), q, k_blocks, v_blocks)
-    return out
 
 
 def _write_kernel(blocks_ref, offs_ref, nk_ref, nv_ref, kb_ref, vb_ref,
@@ -187,8 +182,8 @@ def paged_decode_write(
         in_specs=[
             pl.BlockSpec((1, kh, hd), lambda i, blk, off: (i, 0, 0)),
             pl.BlockSpec((1, kh, hd), lambda i, blk, off: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # aliased k pool (unread)
-            pl.BlockSpec(memory_space=pltpu.ANY),   # aliased v pool (unread)
+            pl.BlockSpec(memory_space=pl.ANY),   # aliased k pool (unread)
+            pl.BlockSpec(memory_space=pl.ANY),   # aliased v pool (unread)
         ],
         out_specs=[
             pl.BlockSpec((1, 1, kh, hd),

@@ -62,6 +62,9 @@ def main(argv=None):
     cfg = get_config(args.arch)
     if not args.full_config:
         cfg = smoke_variant(cfg)
+    dev = jax.devices()[0]
+    print(f"serving {cfg.name} on {dev.platform} ({dev.device_kind}), "
+          f"{len(jax.devices())} device(s)")
 
     key = jax.random.PRNGKey(args.seed)
     if args.store:

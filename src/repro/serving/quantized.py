@@ -42,11 +42,13 @@ def _eligible(name: str, leaf) -> bool:
     return "tail/" in name and leaf.ndim >= 2
 
 
+@jax.jit
 def _quantize_leaf(w) -> Dict[str, jnp.ndarray]:
     """Per-output-channel symmetric int8 of one eligible weight: scale
     reduces over the second-to-last dim (the contraction dim of every
-    block matmul)."""
-    w = jnp.asarray(w).astype(jnp.float32)
+    block matmul).  Jitted so the f32 intermediates fuse away; op by op,
+    a full-width stacked FFN leaf would hold several 3.2 GB f32 copies."""
+    w = w.astype(jnp.float32)
     amax = jnp.max(jnp.abs(w), axis=-2, keepdims=True)
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)
     codes = jnp.clip(jnp.round(w / scale), -127, 127).astype(jnp.int8)

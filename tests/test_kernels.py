@@ -113,6 +113,26 @@ def test_delta_apply_matches_ref(n, k):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=0)
 
 
+@pytest.mark.parametrize("n,k,dtype,donate", [
+    (10000, 1500, jnp.float32, False),     # ragged buffer and delta chunks
+    (12288, 4096, jnp.bfloat16, True),     # in-place, eight full chunks
+])
+def test_delta_apply_spans_delta_chunks(n, k, dtype, donate):
+    """Deltas past one DELTA_CHUNK (the old per-call VMEM bound) run
+    through the kernel's inner chunk axis, not the oracle."""
+    from repro.kernels.delta_apply import DELTA_CHUNK
+
+    assert k > DELTA_CHUNK
+    r = rng(n ^ k)
+    buf = jnp.asarray(r.standard_normal(n), dtype=dtype)
+    idx = jnp.asarray(r.choice(n, size=k, replace=False), dtype=jnp.int32)
+    vals = jnp.asarray(r.standard_normal(k), dtype=dtype)
+    want = ref.delta_apply(buf, idx, vals)
+    got = ops.delta_apply(buf, idx, vals, interpret=True, donate=donate)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
 def test_delta_apply_bf16_buffer():
     r = rng(77)
     buf = jnp.asarray(r.standard_normal(8192), dtype=jnp.bfloat16)

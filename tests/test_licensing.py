@@ -64,6 +64,23 @@ def test_apply_license_excludes_dynamics_params():
     assert (np.asarray(out["layer1"]["kernel"]) == 0).all()
 
 
+def test_apply_license_keeps_leaves_on_device():
+    """Masked leaves are computed where the weights live and equal the
+    op-by-op mask; unmasked leaves pass through by reference."""
+    p = {k: {n: jnp.asarray(a) for n, a in v.items()}
+         for k, v in mlp_params(3).items()}
+    tier = LicenseTier(name="free", masks={"layer": ((0.2, 0.9),)})
+    out = apply_license(p, tier)
+    assert out["out"]["kernel"] is p["out"]["kernel"]
+    assert out["out"]["norm"] is p["out"]["norm"]
+    for name in ("layer1", "layer2"):
+        got = out[name]["kernel"]
+        assert isinstance(got, type(p[name]["kernel"]))
+        np.testing.assert_array_equal(
+            np.asarray(got),
+            np.asarray(mask_weight(p[name]["kernel"], [(0.2, 0.9)])))
+
+
 def test_license_stats_counts_masked():
     p = mlp_params()
     tier = LicenseTier(name="free", masks={"layer1": ((0.0, 100.0),)})
