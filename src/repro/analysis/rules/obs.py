@@ -1,11 +1,11 @@
 """RULE-OBS: telemetry/trace/audit record sites stay behind ``self.obs``.
 
 The observability layer's <3% overhead gate (docs/OBSERVABILITY.md)
-holds because every *record* call in the serving hot path — span
-begin/end/instant/complete/counter on a tracer, ``audit.record``, and
-histogram ``observe`` — is guarded by one pre-computed ``obs`` bool, so
-a ``telemetry=False`` gateway never builds attribute dicts or touches
-the tape.  This rule flags any record site in ``serving/`` that is not
+holds because every *record* call in the serving hot path —
+begin/end/instant/counter and the program ``span`` on a tracer,
+``audit.record``, and histogram ``observe`` — is guarded by one
+pre-computed ``obs`` bool, so a ``telemetry=False`` gateway never builds
+attribute dicts, touches the tape or enters a profiler annotation.  This rule flags any record site in ``serving/`` that is not
 lexically under an ``obs`` guard.
 
 Read-side exports (``chrome_trace``, ``span_names``, ``events``,
@@ -25,7 +25,7 @@ from typing import Iterable, List
 from repro.analysis.lint import Diagnostic, ModuleInfo, ancestors
 from repro.analysis.rules import Rule, _attr_chain
 
-_TRACER_METHODS = {"begin", "end", "instant", "complete", "counter"}
+_TRACER_METHODS = {"begin", "end", "instant", "span", "counter"}
 _EXEMPT_FILES = {"telemetry.py", "tracing.py"}
 _OBS_ONLY = frozenset({"obs"})
 _OBS_AND_AUDIT = frozenset({"obs", "audit"})

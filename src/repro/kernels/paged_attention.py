@@ -138,6 +138,7 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
         interpret=interpret,
+        name="paged_attention",
     )(jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(context_lens, jnp.int32), q, k_blocks, v_blocks)
 
@@ -203,5 +204,6 @@ def paged_decode_write(
         # prefetch args): only the B addressed rows are ever written
         input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
+        name="paged_decode_write",
     )(jnp.asarray(block_ids, jnp.int32), jnp.asarray(offsets, jnp.int32),
       new_k, new_v, k_blocks, v_blocks)

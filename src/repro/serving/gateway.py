@@ -113,11 +113,23 @@ from repro.serving.fleet import ModelSlot
 from repro.serving.paging import cdiv
 from repro.serving.scheduler import (GatewayRequest, RequestState,
                                      ScheduledAction)
+from repro.serving.tracing import NULL_SPAN
 
 
 def _pow2(n: int) -> int:
     """Smallest power of two >= n (bucketing for jit specialization)."""
     return 1 << max(0, int(n) - 1).bit_length()
+
+
+def _phased(name: str):
+    """Run the whole method inside the gateway's program span ``name``."""
+    def wrap(method):
+        @functools.wraps(method)
+        def run(self, *args, **kwargs):
+            with self._phase(name):
+                return method(self, *args, **kwargs)
+        return run
+    return wrap
 
 
 def _finish_lane(logits, seed, n_out, temp, top_k, *, fused, with_rng,
@@ -463,6 +475,11 @@ class LicensedGateway:
         return self.views.get(tier, self.version if version is None else version)
 
     # ------------------------------------------------------------ telemetry
+    def _phase(self, name: str, attrs: Optional[Dict[str, Any]] = None):
+        """Program span ``name`` (the table in ``serving/tracing.py``), or
+        the shared null context when telemetry is off."""
+        return self.tracer.span(name, attrs) if self.obs else NULL_SPAN
+
     def _span(self, req: GatewayRequest, name: Optional[str],
               attrs: Optional[Dict[str, Any]] = None) -> None:
         """Close the request's open lifecycle span and begin ``name``
@@ -477,15 +494,16 @@ class LicensedGateway:
         req._open_span = name
 
     def _note_admission(self, req: GatewayRequest) -> None:
-        """Record a request leaving the queue for a lane: queue-wait
-        histogram (first admission only — a restart's wait is preemption
-        recovery, not admission wait), admit/restart instant, and the
-        prefill lifecycle span."""
+        """Record a request leaving the queue for a lane: ``admit_t`` and
+        the queue-wait histogram (first admission only — a restart's wait
+        is preemption recovery, not admission wait), admit/restart
+        instant, and the prefill lifecycle span."""
+        if req.admit_t is None:
+            req.admit_t = self.clock()
         if not self.obs:
             return
-        now = self.clock()
         if req.preemptions == 0:
-            self.h_queue.observe(now - req.submit_t)
+            self.h_queue.observe(req.admit_t - req.submit_t)
             name = "admit"
         else:
             name = "restart"
@@ -530,6 +548,7 @@ class LicensedGateway:
                                 {"tier": req.license, "reason": error})
         return req
 
+    @_phased("gw:submit")
     def submit(self, prompt, *, license: str = "full", max_new_tokens: int = 16,
                temperature: float = 0.0, top_k: int = 0,
                seed: int = 0, tenant: Optional[str] = None) -> GatewayRequest:
@@ -605,6 +624,7 @@ class LicensedGateway:
         return req
 
     # ------------------------------------------------------------- scheduling
+    @_phased("gw:step")
     def step(self, *, drive_stager: bool = True) -> Optional[ScheduledAction]:
         """Run ONE scheduler iteration (one prefill or decode micro-batch),
         plus — when a staged weight sync is active — ONE bounded stager
@@ -612,28 +632,27 @@ class LicensedGateway:
         ever stalling it.  A ``FleetGateway`` passes
         ``drive_stager=False`` and advances at most one slot's stager
         per fleet iteration itself."""
-        act = self.scheduler.next_action()
+        with self._phase("sched:next"):
+            act = self.scheduler.next_action()
         if act is not None:
             act.model = self.model
-            t0 = self.clock() if self.obs else 0.0
-            if act.kind == "prefill":
-                if self.chunked:
-                    self._run_chunked_prefill(act)
+            attrs = ({"tier": act.tier, "version": act.version}
+                     if self.obs else None)
+            with self._phase("sched:" + act.kind, attrs) as sp:
+                if act.kind == "prefill":
+                    if self.chunked:
+                        self._run_chunked_prefill(act)
+                    else:
+                        self._run_prefill(act)
                 else:
-                    self._run_prefill(act)
-            else:
-                self._run_decode(act)
+                    self._run_decode(act)
+                if self.obs:
+                    attrs["batch"] = len(act.requests)
+                    if act.suffix_bucket is not None:
+                        attrs["suffix_bucket"] = act.suffix_bucket
             if self.obs:
-                t1 = self.clock()
                 (self.h_prefill if act.kind == "prefill"
-                 else self.h_decode).observe(t1 - t0)
-                attrs: Dict[str, Any] = {"tier": act.tier,
-                                         "version": act.version,
-                                         "batch": len(act.requests)}
-                if act.suffix_bucket is not None:
-                    attrs["suffix_bucket"] = act.suffix_bucket
-                self.tracer.complete("sched:" + act.kind, t0, t1,
-                                     attrs=attrs)
+                 else self.h_decode).observe(sp.dur)
                 self.tracer.counter("queue_depth",
                                     len(self.scheduler.waiting))
                 self.tracer.counter("running",
@@ -749,11 +768,13 @@ class LicensedGateway:
         return out
 
     def _run_prefill(self, act: ScheduledAction) -> None:
-        view_params, li = self.views.get(act.tier, act.version)
+        with self._phase("view:get"):
+            view_params, li = self.views.get(act.tier, act.version)
         reqs = act.requests
-        toks = right_align([r.prompt for r in reqs], self.max_prompt,
-                           self.max_batch)
-        seeds, nouts, temps, topks = self._sampling_lanes(reqs)
+        with self._phase("gw:inputs"):
+            toks = right_align([r.prompt for r in reqs], self.max_prompt,
+                               self.max_batch)
+            seeds, nouts, temps, topks = self._sampling_lanes(reqs)
         # longest-cached-prefix lookup (before any allocation: matching
         # increfs the chains, so eviction under this batch's own pressure
         # can never free a block another lane is about to adopt).  The
@@ -762,18 +783,19 @@ class LicensedGateway:
         scope = (act.tier, act.version)
         matches: List[Tuple[List[int], int]] = []
         if self.prefix is not None:       # paged-only by construction
-            for i in range(len(reqs)):
-                blocks, ntok = self.prefix.match(scope, toks[i])
-                # always recompute >= 1 token: the first sampled token
-                # needs the last prompt position's logits
-                capped = min(ntok, self.max_prompt - 1)
-                if capped == 0 and blocks:
-                    # the cap zeroed a real match (max_prompt == 1): the
-                    # chain is unusable — release the match's references
-                    for b in blocks:
-                        self._decref_block(b)
-                    blocks = []
-                matches.append((blocks, capped))
+            with self._phase("pool:grow"):
+                for i in range(len(reqs)):
+                    blocks, ntok = self.prefix.match(scope, toks[i])
+                    # always recompute >= 1 token: the first sampled
+                    # token needs the last prompt position's logits
+                    capped = min(ntok, self.max_prompt - 1)
+                    if capped == 0 and blocks:
+                        # the cap zeroed a real match (max_prompt == 1):
+                        # the chain is unusable — release its references
+                        for b in blocks:
+                            self._decref_block(b)
+                        blocks = []
+                    matches.append((blocks, capped))
         hit = any(n > 0 for _, n in matches)
         if hit:
             lanes = [self.scheduler.start(r) for r in reqs]
@@ -783,24 +805,27 @@ class LicensedGateway:
                 act, toks, matches, lanes, view_params, li,
                 (seeds, nouts, temps, topks))
         else:
-            prefill, _ = self._steps(reqs)
-            outs, lane_caches = prefill(view_params, jnp.asarray(toks),
-                                        self._zero_lanes, seeds, nouts,
-                                        temps, topks, li)
+            with self._phase("step:dispatch"):
+                prefill, _ = self._steps(reqs)
+                outs, lane_caches = prefill(view_params, jnp.asarray(toks),
+                                            self._zero_lanes, seeds, nouts,
+                                            temps, topks, li)
             lanes = [self.scheduler.start(r) for r in reqs]
             for r in reqs:
                 self._note_admission(r)
             if self.paged:
-                for r in reqs:
-                    r.blocks = self._alloc_blocks(self._prefill_blocks)
-                self._note_block_use()
-                tables = self.pool.pad_tables([r.blocks for r in reqs],
-                                              self.max_batch)
-                self.pool.scatter(self.pool.pad_lanes(lanes, self.max_batch),
-                                  tables, lane_caches)
-            else:
-                self.pool.scatter(self.pool.pad_lanes(lanes, self.max_batch),
-                                  lane_caches)
+                with self._phase("pool:grow"):
+                    for r in reqs:
+                        r.blocks = self._alloc_blocks(self._prefill_blocks)
+                    self._note_block_use()
+            with self._phase("pool:absorb"):
+                lane_ids = self.pool.pad_lanes(lanes, self.max_batch)
+                if self.paged:
+                    tables = self.pool.pad_tables([r.blocks for r in reqs],
+                                                  self.max_batch)
+                    self.pool.scatter(lane_ids, tables, lane_caches)
+                else:
+                    self.pool.scatter(lane_ids, lane_caches)
             self.stats["prefill_lane_tokens"] += self.max_prompt * len(reqs)
         self.stats["max_running"] = max(self.stats["max_running"],
                                         len(self.scheduler.running))
@@ -810,15 +835,17 @@ class LicensedGateway:
             for i, r in enumerate(reqs):
                 self.prefix.insert(scope, toks[i],
                                    r.blocks[: self._prefill_blocks])
-        outs = np.asarray(outs)
-        now = self.clock()
-        for i, r in enumerate(reqs):
-            r.pos = self.max_prompt
-            self._note_first_token(r, now)
-            if self.fuse_sampling:
-                self._emit(r, tok=int(outs[i]))
-            else:
-                self._emit(r, logits_row=outs[i])
+        with self._phase("step:fetch"):
+            outs = np.asarray(outs)
+        with self._phase("gw:emit"):
+            now = self.clock()
+            for i, r in enumerate(reqs):
+                r.pos = self.max_prompt
+                self._note_first_token(r, now)
+                if self.fuse_sampling:
+                    self._emit(r, tok=int(outs[i]))
+                else:
+                    self._emit(r, logits_row=outs[i])
         self.stats["prefill_batches"] += 1
         if act.suffix_bucket is not None:
             self.bucket_batches[act.suffix_bucket] = \
@@ -845,34 +872,42 @@ class LicensedGateway:
         sub = np.zeros((self.max_batch, w), np.int32)
         poss = np.zeros(self.max_batch, np.int32)
         lasts = np.zeros(self.max_batch, np.int32)
-        for i, r in enumerate(reqs):
-            blocks, ntok = matches[i]
-            sub[i, : suffix[i]] = toks[i, ntok:]
-            sub[i, suffix[i]:] = toks[i, -1]       # right pad: junk region
-            poss[i] = ntok
-            lasts[i] = suffix[i] - 1
-            fresh = self._alloc_blocks(self._prefill_blocks - len(blocks))
-            r.blocks = list(blocks) + fresh
-            r.prefix_tokens = ntok
-            self.stats["prefix_tokens_reused"] += ntok
-            if self.obs and ntok:
-                self.tracer.instant("prefix_hit", r.rid, {"tokens": ntok})
+        with self._phase("pool:grow"):
+            for i, r in enumerate(reqs):
+                blocks, ntok = matches[i]
+                sub[i, : suffix[i]] = toks[i, ntok:]
+                sub[i, suffix[i]:] = toks[i, -1]   # right pad: junk region
+                poss[i] = ntok
+                lasts[i] = suffix[i] - 1
+                fresh = self._alloc_blocks(self._prefill_blocks - len(blocks))
+                r.blocks = list(blocks) + fresh
+                r.prefix_tokens = ntok
+                self.stats["prefix_tokens_reused"] += ntok
+                if self.obs and ntok:
+                    self.tracer.instant("prefix_hit", r.rid,
+                                        {"tokens": ntok})
+            self._note_block_use()
         self.stats["prefill_lane_tokens"] += w * len(reqs)
-        self._note_block_use()
-        lane_ids = self.pool.pad_lanes(lanes, self.max_batch)
-        tables = self.pool.pad_tables([r.blocks for r in reqs],
-                                      self.max_batch)
-        caches = self.pool.gather(lane_ids, tables, fresh_lane_state=True)
-        prefill = self._prefix_steps(reqs)
-        outs, lane_caches = prefill(view_params, jnp.asarray(sub), caches,
-                                    jnp.asarray(poss), jnp.asarray(lasts),
-                                    seeds, nouts, temps, topks, li)
-        # the step's len accounting saw only W suffix tokens; pin the
-        # counters to the true logical fill before they reach the pool
-        lane_caches = self.pool.override_counters(lane_caches,
-                                                  self.max_prompt)
-        self.pool.scatter(lane_ids, self._scatter_tables(tables, reqs),
-                          lane_caches)
+        with self._phase("gw:inputs"):
+            lane_ids = self.pool.pad_lanes(lanes, self.max_batch)
+        with self._phase("pool:gather"):
+            tables = self.pool.pad_tables([r.blocks for r in reqs],
+                                          self.max_batch)
+            caches = self.pool.gather(lane_ids, tables,
+                                      fresh_lane_state=True)
+        with self._phase("step:dispatch"):
+            prefill = self._prefix_steps(reqs)
+            outs, lane_caches = prefill(view_params, jnp.asarray(sub),
+                                        caches, jnp.asarray(poss),
+                                        jnp.asarray(lasts), seeds, nouts,
+                                        temps, topks, li)
+        with self._phase("pool:absorb"):
+            # the step's len accounting saw only W suffix tokens; pin the
+            # counters to the true logical fill before they reach the pool
+            lane_caches = self.pool.override_counters(lane_caches,
+                                                      self.max_prompt)
+            self.pool.scatter(lane_ids, self._scatter_tables(tables, reqs),
+                              lane_caches)
         return outs
 
     # ------------------------------------------------------ chunked prefill
@@ -885,7 +920,8 @@ class LicensedGateway:
         its first token in a single step — the legacy one-step-prefill
         latency."""
         if act.requests[0].state is not RequestState.PREFILLING:
-            self._admit_chunked(act)
+            with self._phase("pool:grow"):
+                self._admit_chunked(act)
         self._run_prefill_chunk(act)
 
     def _admit_chunked(self, act: ScheduledAction) -> None:
@@ -949,7 +985,8 @@ class LicensedGateway:
         lane whose cursor reaches the prompt end emits its first token
         (the last chunk's selected row is the last prompt position's
         logits) and enters decode."""
-        view_params, li = self.views.get(act.tier, act.version)
+        with self._phase("view:get"):
+            view_params, li = self.views.get(act.tier, act.version)
         reqs = act.requests
         w = self.chunk_size
         bs = self.pool.block_size
@@ -969,60 +1006,68 @@ class LicensedGateway:
         need = max(cdiv(r.cursor + w, bs) for r in reqs)
         cols = min(self.pool.blocks_per_lane, _pow2(need))
         self._note_retrace("prefill_chunk", (b, cols))
-        sub = np.zeros((b, w), np.int32)
-        poss = np.zeros(b, np.int32)
-        lasts = np.zeros(b, np.int32)
-        fills = np.zeros(b, np.int32)
-        valid = np.zeros(len(reqs), np.int32)
-        for i, r in enumerate(reqs):
-            v = min(w, len(r.prompt) - r.cursor)
-            valid[i] = v
-            sub[i, :v] = r.prompt[r.cursor: r.cursor + v]
-            sub[i, v:] = int(r.prompt[-1])     # right pad: junk region
-            poss[i] = r.cursor
-            lasts[i] = v - 1
-            fills[i] = r.cursor + v
-        seeds, nouts, temps, topks = self._sampling_lanes(reqs, b)
-        lane_ids = self.pool.pad_lanes([r.lane for r in reqs], b)
-        tables = self.pool.pad_tables([r.blocks[:cols] for r in reqs], b,
-                                      n_cols=cols)
-        # per-lane counters are pinned to the true fill below, and the
-        # attend-cache step masks positionally — fresh lane state is
-        # correct for EVERY chunk, not just the first
-        caches = self.pool.gather(lane_ids, tables, fresh_lane_state=True)
-        prefill = self._prefix_steps(reqs)
-        outs, lane_caches = prefill(view_params, jnp.asarray(sub), caches,
-                                    jnp.asarray(poss), jnp.asarray(lasts),
-                                    seeds, nouts, temps, topks, li)
-        lane_caches = self.pool.override_counters(lane_caches,
-                                                  jnp.asarray(fills))
-        self.pool.scatter(lane_ids, self._scatter_tables(tables, reqs),
-                          lane_caches)
+        with self._phase("gw:inputs"):
+            sub = np.zeros((b, w), np.int32)
+            poss = np.zeros(b, np.int32)
+            lasts = np.zeros(b, np.int32)
+            fills = np.zeros(b, np.int32)
+            valid = np.zeros(len(reqs), np.int32)
+            for i, r in enumerate(reqs):
+                v = min(w, len(r.prompt) - r.cursor)
+                valid[i] = v
+                sub[i, :v] = r.prompt[r.cursor: r.cursor + v]
+                sub[i, v:] = int(r.prompt[-1])     # right pad: junk region
+                poss[i] = r.cursor
+                lasts[i] = v - 1
+                fills[i] = r.cursor + v
+            seeds, nouts, temps, topks = self._sampling_lanes(reqs, b)
+            lane_ids = self.pool.pad_lanes([r.lane for r in reqs], b)
+        with self._phase("pool:gather"):
+            tables = self.pool.pad_tables([r.blocks[:cols] for r in reqs],
+                                          b, n_cols=cols)
+            # per-lane counters are pinned to the true fill below, and
+            # the attend-cache step masks positionally — fresh lane state
+            # is correct for EVERY chunk, not just the first
+            caches = self.pool.gather(lane_ids, tables,
+                                      fresh_lane_state=True)
+        with self._phase("step:dispatch"):
+            prefill = self._prefix_steps(reqs)
+            outs, lane_caches = prefill(view_params, jnp.asarray(sub),
+                                        caches, jnp.asarray(poss),
+                                        jnp.asarray(lasts), seeds, nouts,
+                                        temps, topks, li)
+        with self._phase("pool:absorb"):
+            lane_caches = self.pool.override_counters(lane_caches,
+                                                      jnp.asarray(fills))
+            self.pool.scatter(lane_ids, self._scatter_tables(tables, reqs),
+                              lane_caches)
         self.stats["prefill_lane_tokens"] += w * len(reqs)
         self.stats["prefill_chunks"] += 1
-        outs = np.asarray(outs)
-        now = self.clock()
-        scope = (act.tier, act.version)
-        for i, r in enumerate(reqs):
-            r.cursor += int(valid[i])
-            if self.obs:
-                self.tracer.instant("prefill_chunk", r.rid,
-                                    {"cursor": r.cursor,
-                                     "tokens": int(valid[i])})
-            if r.cursor < len(r.prompt):
-                continue
-            r.state = RequestState.RUNNING
-            r.pos = len(r.prompt)
-            self._note_first_token(r, now)
-            if self.prefix is not None:
-                # donate the TRUE-token chain (full blocks + partial
-                # tail) so any future prompt sharing the prefix — at any
-                # length — adopts it
-                self.prefix.insert(scope, r.prompt, r.blocks)
-            if self.fuse_sampling:
-                self._emit(r, tok=int(outs[i]))
-            else:
-                self._emit(r, logits_row=outs[i])
+        with self._phase("step:fetch"):
+            outs = np.asarray(outs)
+        with self._phase("gw:emit"):
+            now = self.clock()
+            scope = (act.tier, act.version)
+            for i, r in enumerate(reqs):
+                r.cursor += int(valid[i])
+                if self.obs:
+                    self.tracer.instant("prefill_chunk", r.rid,
+                                        {"cursor": r.cursor,
+                                         "tokens": int(valid[i])})
+                if r.cursor < len(r.prompt):
+                    continue
+                r.state = RequestState.RUNNING
+                r.pos = len(r.prompt)
+                self._note_first_token(r, now)
+                if self.prefix is not None:
+                    # donate the TRUE-token chain (full blocks + partial
+                    # tail) so any future prompt sharing the prefix — at
+                    # any length — adopts it
+                    self.prefix.insert(scope, r.prompt, r.blocks)
+                if self.fuse_sampling:
+                    self._emit(r, tok=int(outs[i]))
+                else:
+                    self._emit(r, logits_row=outs[i])
 
     def _try_alloc_one(self) -> Optional[int]:
         """One block from the free list, reclaiming retained prefix chains
@@ -1153,21 +1198,25 @@ class LicensedGateway:
 
     def _run_decode(self, act: ScheduledAction) -> None:
         if self.paged:
-            act.requests = self._grow_block_tables(act.requests)
+            with self._phase("pool:grow"):
+                act.requests = self._grow_block_tables(act.requests)
             if not act.requests:
                 return                     # whole batch preempted
             if self.sanitizer is not None:
                 # post-CoW: every table entry live, write targets private
                 self.sanitizer.check_decode_writes(act.requests, self.pool)
-        view_params, li = self.views.get(act.tier, act.version)
+        with self._phase("view:get"):
+            view_params, li = self.views.get(act.tier, act.version)
         reqs = act.requests
-        lanes = self.pool.pad_lanes([r.lane for r in reqs], self.max_batch)
-        toks = np.zeros(self.max_batch, np.int32)
-        poss = np.zeros(self.max_batch, np.int32)
-        for i, r in enumerate(reqs):
-            toks[i] = r.out_tokens[-1]
-            poss[i] = r.pos
-        seeds, nouts, temps, topks = self._sampling_lanes(reqs)
+        with self._phase("gw:inputs"):
+            lanes = self.pool.pad_lanes([r.lane for r in reqs],
+                                        self.max_batch)
+            toks = np.zeros(self.max_batch, np.int32)
+            poss = np.zeros(self.max_batch, np.int32)
+            for i, r in enumerate(reqs):
+                toks[i] = r.out_tokens[-1]
+                poss[i] = r.pos
+            seeds, nouts, temps, topks = self._sampling_lanes(reqs)
         if self.paged and self.kernel_decode:
             # kernel-resident path: the pool's block arrays ARE the cache
             # operands.  Tables are trimmed to the batch's used width, so
@@ -1176,46 +1225,52 @@ class LicensedGateway:
             # index (the target is private — _grow_block_tables CoW'd a
             # shared tail before this step), and shared prefix blocks are
             # never write targets, so no null-redirect is needed.
-            used = max(r.pos // self.pool.block_size + 1 for r in reqs)
-            self._note_retrace("decode_width", used)
-            tables = self.pool.pad_tables([r.blocks[:used] for r in reqs],
-                                          self.max_batch, used)
-            caches = self.pool.decode_cache(lanes)
-            step = self._paged_decode_step(reqs)
-            outs, caches = step(view_params, jnp.asarray(toks), caches,
-                                jnp.asarray(tables), jnp.asarray(poss),
-                                seeds, nouts, temps, topks, li)
-            self.pool.absorb_decode(lanes, caches)
+            with self._phase("pool:gather"):
+                used = max(r.pos // self.pool.block_size + 1 for r in reqs)
+                self._note_retrace("decode_width", used)
+                tables = self.pool.pad_tables(
+                    [r.blocks[:used] for r in reqs], self.max_batch, used)
+                caches = self.pool.decode_cache(lanes)
+            with self._phase("step:dispatch"):
+                step = self._paged_decode_step(reqs)
+                outs, caches = step(view_params, jnp.asarray(toks), caches,
+                                    jnp.asarray(tables), jnp.asarray(poss),
+                                    seeds, nouts, temps, topks, li)
+            with self._phase("pool:absorb"):
+                self.pool.absorb_decode(lanes, caches)
             self.stats["resident_decode_steps"] += 1
         else:
-            if self.paged:
-                tables = self.pool.pad_tables([r.blocks for r in reqs],
-                                              self.max_batch)
-                caches = self.pool.gather(lanes, tables)
-            else:
-                caches = self.pool.gather(lanes)
-            _, decode = self._steps(reqs)
-            outs, caches = decode(view_params, jnp.asarray(toks), caches,
-                                  jnp.asarray(poss), seeds, nouts, temps,
-                                  topks, li)
-            if self.paged:
-                # shared (prefix-cache) blocks are read-only: redirect
-                # their redundant write-back to the null block (the write
-                # target itself is always private — CoW'd above)
-                wb = (self._scatter_tables(tables, reqs)
-                      if self.prefix is not None else tables)
-                self.pool.scatter(lanes, wb, caches)
-            else:
-                self.pool.scatter(lanes, caches)
-        outs = np.asarray(outs)
-        for i, r in enumerate(reqs):
-            r.pos += 1
-            if self.obs:
-                self.tracer.instant("decode_step", r.rid, {"pos": r.pos})
-            if self.fuse_sampling:
-                self._emit(r, tok=int(outs[i]))
-            else:
-                self._emit(r, logits_row=outs[i])
+            with self._phase("pool:gather"):
+                if self.paged:
+                    tables = self.pool.pad_tables([r.blocks for r in reqs],
+                                                  self.max_batch)
+                    caches = self.pool.gather(lanes, tables)
+                else:
+                    caches = self.pool.gather(lanes)
+            with self._phase("step:dispatch"):
+                _, decode = self._steps(reqs)
+                outs, caches = decode(view_params, jnp.asarray(toks), caches,
+                                      jnp.asarray(poss), seeds, nouts, temps,
+                                      topks, li)
+            with self._phase("pool:absorb"):
+                if self.paged:
+                    # shared (prefix-cache) blocks are read-only: redirect
+                    # their redundant write-back to the null block (the
+                    # write target itself is always private — CoW'd above)
+                    wb = (self._scatter_tables(tables, reqs)
+                          if self.prefix is not None else tables)
+                    self.pool.scatter(lanes, wb, caches)
+                else:
+                    self.pool.scatter(lanes, caches)
+        with self._phase("step:fetch"):
+            outs = np.asarray(outs)
+        with self._phase("gw:emit"):
+            for i, r in enumerate(reqs):
+                r.pos += 1
+                if self.fuse_sampling:
+                    self._emit(r, tok=int(outs[i]))
+                else:
+                    self._emit(r, logits_row=outs[i])
         self.stats["decode_steps"] += 1
 
     def _emit(self, req: GatewayRequest, tok: Optional[int] = None,

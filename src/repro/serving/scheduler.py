@@ -93,6 +93,8 @@ class GatewayRequest:
     logits_rows: Optional[List[np.ndarray]] = None   # record_logits only
     error: Optional[str] = None
     submit_t: float = 0.0
+    admit_t: Optional[float] = None          # first lane assignment; a
+                                             # restart keeps it
     first_token_t: Optional[float] = None
     finish_t: Optional[float] = None
 

@@ -1,4 +1,4 @@
-"""Request lifecycle tracing, Chrome trace_event export, licensing audit.
+"""Request lifecycle tracing, program spans, Chrome export, licensing audit.
 
 Three pieces, all zero-dependency and always-on cheap:
 
@@ -6,22 +6,57 @@ Three pieces, all zero-dependency and always-on cheap:
   O(1) append of a plain tuple ``(ts, kind, rid, name, attrs)`` onto a
   bounded deque; no dict churn, no string formatting on the hot path.
   The span taxonomy (``docs/OBSERVABILITY.md``) covers the full request
-  lifecycle: ``submit → admit → prefix_hit → prefill_chunk×N →
-  decode_step×M → preempt/restart → finish``, plus scheduler actions
-  and stager phases as instant/complete events and pool occupancy as
-  counter samples.
+  lifecycle: ``submit → admit → prefix_hit → prefill_chunk×N → decode →
+  preempt/restart → finish``, plus the program spans below and pool
+  occupancy as counter samples.
 * Chrome ``trace_event`` export — :meth:`TraceRecorder.chrome_trace`
   renders the tape into the JSON Array Format that Perfetto /
   ``chrome://tracing`` load directly: request spans as matched ``B``/``E``
-  pairs (one tid per request), scheduler actions and stager phases as
-  ``X`` complete events on pseudo-threads, occupancy as ``C`` counter
-  tracks, and ``M`` metadata naming every track.  A fleet merges slot
-  tapes with one *pid per model*.
+  pairs (one tid per request), program spans as nested ``X`` complete
+  events on the scheduler and stager pseudo-threads, occupancy as ``C``
+  counter tracks, and ``M`` metadata naming every track.  A fleet merges
+  slot tapes with one *pid per model*.
 * :class:`AuditLog` — the licensing ledger: append-only
   ``(ts, seq, event, attrs)`` records for tier grants/revocations,
   view-cache materializations, version installs/flips, and per-tenant
   quota/rate rejections — "who could run which tier at which version
   when", answerable after the fact.
+
+Program spans
+-------------
+:meth:`TraceRecorder.span` times one phase of the serving loop on two
+clocks at once: a ``jax.profiler.TraceAnnotation`` of exactly the span's
+name (in the profiler trace, on the device trace's clock, beside the
+ops it dispatched) and, when the recorder is enabled, an ``X`` event on
+the tape (the recorder's injectable clock).  Call sites sit behind the
+gateway's ``obs`` bool, so ``telemetry=False`` emits neither.  The names
+are literal and stable — the benchmark's per-layer metrics key on them:
+
+===================================  =======================================  =====================
+span                                 covers                                   layer
+===================================  =======================================  =====================
+``gw:step``                          one whole ``LicensedGateway.step()``     gateway and scheduler
+``sched:next``                       ``scheduler.next_action()``              gateway and scheduler
+``sched:prefill``, ``sched:decode``  the scheduled action                     gateway and scheduler
+``view:get``                         ``views.get(tier, version)``             gateway and scheduler
+``pool:grow``                        decode: ``_grow_block_tables`` (growth,  paged pool
+                                     CoW, preemption); prefill: the
+                                     admission's prefix match and blocks
+``gw:inputs``                        host token, position and sampling-lane   gateway and scheduler
+                                     arrays, and ``pad_lanes``
+``pool:gather``                      ``pad_tables`` with ``decode_cache`` or  paged pool
+                                     ``gather``
+``step:dispatch``                    the jitted program call, until it        model step
+                                     returns
+``pool:absorb``                      ``absorb_decode``, or                    paged pool
+                                     ``override_counters`` with ``scatter``
+``step:fetch``                       ``np.asarray(outs)``, the one            model step
+                                     device-to-host copy per step
+``gw:emit``                          the per-lane loop after the fetch        gateway and scheduler
+                                     (tokens, finish, release)
+``stager:<phase>``                   one update-stager step                   updates
+``gw:submit``                        ``LicensedGateway.submit()``             gateway and scheduler
+===================================  =======================================  =====================
 
 :func:`validate_chrome_trace` is the acceptance check the test-suite and
 benchmark share: parseable JSON, non-decreasing timestamps, and
@@ -29,19 +64,22 @@ balanced ``B``/``E`` pairs per (pid, tid).
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import time
 from collections import deque
 from typing import (Any, Callable, Dict, Iterable, List, Optional, Tuple)
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["TraceRecorder", "AuditLog", "validate_chrome_trace",
-           "merge_chrome_traces", "SCHED_TID", "STAGER_TID"]
+           "merge_chrome_traces", "SCHED_TID", "STAGER_TID", "NULL_SPAN"]
 
 # Span kinds (ph in the Chrome mapping):
 #   "B"/"E"  span begin/end          (per-request lifecycle phases)
 #   "i"      instant                 (submit, admit, prefix_hit, preempt, ...)
-#   "X"      complete w/ duration    (scheduler action, stager phase)
+#   "X"      complete w/ duration    (program spans: scheduler, stager)
 #   "C"      counter sample          (pool occupancy, queue depth)
 
 SCHED_TID = 0           # pseudo-thread for scheduler actions
@@ -49,13 +87,50 @@ STAGER_TID = 1          # pseudo-thread for stager phases
 _COUNTER_TID = 2        # counters hang off the process track
 _RID_TID_BASE = 10      # request rid r -> tid 10 + r
 
+# what a span call site enters when telemetry is off
+NULL_SPAN = contextlib.nullcontext()
+
+
+class _Span:
+    """One program span: a profiler ``TraceAnnotation`` named exactly
+    ``name``, and (recorder enabled) an ``X`` event on the tape at exit.
+    ``dur`` holds the recorder-clock duration once the span has closed;
+    ``attrs`` may be filled in until then."""
+
+    __slots__ = ("rec", "name", "attrs", "tid", "t0", "dur", "_ann")
+
+    def __init__(self, rec: "TraceRecorder", name: str,
+                 attrs: Optional[Dict[str, Any]], tid: int):
+        self.rec = rec
+        self.name = name
+        self.attrs = attrs
+        self.tid = tid
+        self.dur = 0.0
+
+    def __enter__(self) -> "_Span":
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        if self.rec.enabled:
+            self.t0 = self.rec.clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        rec = self.rec
+        if rec.enabled:
+            self.dur = rec.clock() - self.t0
+            rec.events.append((self.t0, "X", -1 - self.tid, self.name,
+                               self.attrs, self.dur))
+        self._ann.__exit__(*exc)
+        return False
+
 
 class TraceRecorder:
     """Bounded per-gateway event tape with Chrome trace_event export.
 
-    ``record*`` methods are the only hot-path surface: one tuple append
-    each, guarded by ``enabled``.  Everything else (per-request slicing,
-    Chrome JSON rendering) walks the tape at export time.
+    The record methods and :meth:`span` are the only hot-path surface:
+    one tuple append each, guarded by ``enabled``.  Everything else
+    (per-request slicing, Chrome JSON rendering) walks the tape at export
+    time.
     """
 
     __slots__ = ("clock", "enabled", "events", "_t0")
@@ -87,13 +162,11 @@ class TraceRecorder:
             return
         self.events.append((self.clock(), "E", rid, name, attrs, None))
 
-    def complete(self, name: str, start: float, end: float, *,
-                 tid: int = SCHED_TID,
-                 attrs: Optional[Dict[str, Any]] = None) -> None:
-        """X event with explicit duration, on a pseudo-thread track."""
-        if not self.enabled:
-            return
-        self.events.append((start, "X", -1 - tid, name, attrs, end - start))
+    def span(self, name: str, attrs: Optional[Dict[str, Any]] = None, *,
+             tid: int = SCHED_TID) -> _Span:
+        """Context manager timing one program phase on the profiler's
+        clock and (enabled) the tape's; see the module's span table."""
+        return _Span(self, name, attrs, tid)
 
     def counter(self, name: str, value: float) -> None:
         if not self.enabled:
@@ -139,7 +212,7 @@ class TraceRecorder:
                            "name": name, "ts": us, "args": {"value": extra}})
                 continue
             if ph == "X":
-                tid = -1 - rid          # complete() encodes tid as -1-tid
+                tid = -1 - rid          # span() encodes tid as -1-tid
                 ev.append({"ph": "X", "pid": pid, "tid": tid, "name": name,
                            "ts": us, "dur": max(0.0, extra * 1e6),
                            "args": args})

@@ -70,7 +70,7 @@ from repro.core.pytree_io import flatten_params, unflatten_like
 from repro.core.transport import (PayloadCorruption, RetryPolicy, Transport,
                                   TransportError, TransportTimeout,
                                   as_transport)
-from repro.serving.tracing import STAGER_TID
+from repro.serving.tracing import NULL_SPAN, STAGER_TID
 
 # the cursor-protocol fields whose ownership moves with the fetch
 # worker (see the guarded-by annotations in __init__ and
@@ -456,24 +456,25 @@ class UpdateStager:
         phase = self.phase
         self.stats_["steps"] += 1
         gw = self.gw
-        t0 = gw.clock() if gw.obs else 0.0
+        span = (gw.tracer.span("stager:" + phase,
+                               {"to_version": self.to_version},
+                               tid=STAGER_TID)
+                if gw.obs else NULL_SPAN)
         try:
-            if phase == "stage":
-                self._step_stage()
-            elif phase == "requant":
-                self._step_requant()
-            elif phase == "prewarm":
-                self._step_prewarm()
-            elif phase == "flip":
-                self._flip()
+            with span:
+                if phase == "stage":
+                    self._step_stage()
+                elif phase == "requant":
+                    self._step_requant()
+                elif phase == "prewarm":
+                    self._step_prewarm()
+                elif phase == "flip":
+                    self._flip()
         except BaseException:
             self.abort()
             raise
         if gw.obs:
-            t1 = gw.clock()
-            gw.h_stager.observe(t1 - t0)
-            gw.tracer.complete("stager:" + phase, t0, t1, tid=STAGER_TID,
-                               attrs={"to_version": self.to_version})
+            gw.h_stager.observe(span.dur)
         return phase
 
     def abort(self) -> None:

@@ -108,6 +108,22 @@ def test_obs_accepts_guard_styles(tmp_path):
     assert _diags(tmp_path, "obs") == []
 
 
+def test_obs_flags_unguarded_program_span(tmp_path):
+    # a span enters a profiler annotation and writes the tape: the same
+    # guard applies, and the shared-null-context ternary satisfies it
+    _write(tmp_path, "serving/gateway.py", (
+        "class G:\n"
+        "    def step(self):\n"
+        "        with self.tracer.span('gw:step'):\n"
+        "            pass\n"
+        "        with (self.tracer.span('gw:emit') if self.obs\n"
+        "              else NULL_SPAN):\n"
+        "            pass\n"))
+    out = _diags(tmp_path, "obs")
+    assert [d.line for d in out] == [3]
+    assert "tracer.span" in out[0].message
+
+
 def test_obs_exempts_instrument_implementations(tmp_path):
     _write(tmp_path, "serving/telemetry.py", _OBS_BAD)
     _write(tmp_path, "serving/tracing.py", _OBS_BAD)

@@ -227,6 +227,7 @@ def test_telemetry_off_leaves_no_wake(setup):
     assert r.state == RequestState.DONE
     assert not gw.obs
     assert len(gw.tracer.events) == 0
+    assert r.admit_t is not None              # stamped with telemetry off
     assert gw.h_ttft.count == 0 and gw.h_gap.count == 0
     assert gw.audit_events() == []
     validate_gateway_metrics(gw.metrics())    # schema holds either way
@@ -274,6 +275,15 @@ def test_preempt_restart_spans_and_ttft_counted_once(setup):
     validate_chrome_trace(gw.chrome_trace())
     assert gw.h_ttft.count == len(reqs)       # once per request, ever
     assert gw.h_queue.count == len(reqs)      # first admission only
+    # admit_t is stamped at the first admission and kept by the restart;
+    # the queue-wait histogram observes exactly admit_t - submit_t
+    for r in victims:
+        evs = gw.tracer.request_events(r.rid)
+        admit = next(e["ts"] for e in evs if e["name"] == "admit")
+        preempt = min(e["ts"] for e in evs if e["name"] == "preempt")
+        assert r.submit_t <= r.admit_t <= admit < preempt
+    assert gw.h_queue.sum == pytest.approx(
+        sum(r.admit_t - r.submit_t for r in reqs))
 
 
 # --------------------------------------------------------- staged-sync audit
