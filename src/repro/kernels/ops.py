@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Handles padding to block multiples, backend selection (interpret=True on
-CPU so the kernel *body* is what runs in tests), and the pure-jnp fallback
-for shapes too small to tile.
+Handles padding or exact tiling to block multiples, backend selection
+(interpret=True on CPU so the kernel *body* is what runs in tests), and
+the pure-jnp fallback of the masked dequant and the delta scatter for
+shapes too small to tile.
 """
 from __future__ import annotations
 
@@ -33,37 +34,71 @@ def _pad_to(x: jnp.ndarray, mults: Tuple[int, ...], value=0) -> jnp.ndarray:
     return jnp.pad(x, pads, constant_values=value)
 
 
-def quant_matmul(
-    x: jnp.ndarray,
-    codes: jnp.ndarray,
-    scale: jnp.ndarray,
-    *,
-    out_dtype=None,
-    block_m: int = 128,
-    block_n: int = 128,
-    block_k: int = 512,
-    interpret: Optional[bool] = None,
-) -> jnp.ndarray:
-    """Activation (…,K) × int8 weights (K,N) with per-channel scales (N,)."""
-    out_dtype = out_dtype or x.dtype
-    interpret = _on_cpu() if interpret is None else interpret
-    lead = x.shape[:-1]
-    m = int(np.prod(lead)) if lead else 1
-    k = x.shape[-1]
-    n = codes.shape[-1]
-    x2 = x.reshape(m, k)
-    # tiny shapes: pallas tiling has no win; use the oracle (identical math)
-    if m * n * k < 128 * 128 * 128:
-        return ref.quant_matmul(x2, codes, scale, out_dtype).reshape(*lead, n)
-    bm = min(block_m, max(8, 1 << (m - 1).bit_length()))
-    xp = _pad_to(x2, (bm, block_k))
-    cp = _pad_to(codes, (block_k, block_n))
-    sp = _pad_to(scale, (block_n,))
-    out = _qmm.quant_matmul(
-        xp, cp, sp, block_m=bm, block_n=block_n, block_k=block_k,
-        out_dtype=out_dtype, interpret=interpret,
-    )
-    return out[:m, :n].reshape(*lead, n)
+def _tiles(dim: int, cap: int):
+    """Block sizes that tile ``dim`` exactly, largest first: its divisors
+    that are multiples of 128 and at most ``cap``, or ``dim`` itself."""
+    fits = [b for b in range(128, min(dim, cap) + 1, 128) if dim % b == 0]
+    return sorted(fits, reverse=True) or [dim]
+
+
+def _licensed_blocks(m: int, k: int, n: int, itemsize: int):
+    """(block_m, block_k, block_n) for :func:`licensed_matmul`: the weight
+    is tiled exactly (padding a stacked weight would copy it), tiles hold
+    at most 1M codes, and the blocks' VMEM — double-buffered activation,
+    codes and output, the f32 accumulator and the mask's int32
+    temporaries — stays within ~3/4 of the kernel's limit.  Rows are one
+    block where they fit (up to 2048), so every weight tile is read and
+    masked once per call; else the largest row block that fits.  Raises
+    ValueError when no blocks fit."""
+    budget = _qmm.VMEM_LIMIT * 3 // 4
+    for bm in ([m] if m <= 2048 else []) + [512, 256, 128, 8]:
+        best = None
+        for bn in _tiles(n, 4096):
+            for bk in _tiles(k, 4096):
+                tile = bk * bn
+                vmem = (2 * bm * bk * itemsize + 2 * tile + bm * bn * 4
+                        + 2 * bm * bn * itemsize + 4 * tile * 4)
+                if tile <= 1 << 20 and vmem <= budget and (
+                        best is None or (tile, bn) > (best[1] * best[2], best[2])):
+                    best = (bm, bk, bn)
+        if best is not None:
+            return best
+    raise ValueError(f"no Pallas blocks tile an ({m}x{k}) @ ({k}x{n}) "
+                     f"int8 matmul within {budget} bytes of VMEM")
+
+
+def licensed_matmul(x: jnp.ndarray, codes: jnp.ndarray, scale: jnp.ndarray,
+                    dead=None, unit=0, *, interpret: bool = False):
+    """``x (…,K) @ mask(codes) * scale`` through the Pallas kernel, output
+    in ``x.dtype``; ``codes`` (K,N) or (U,K,N) read at ``unit``, ``scale``
+    (N,) or (1,N), ``dead`` None or a tier's code bounds (n,N) each.
+
+    Under ``vmap`` over the activation alone (the lane-vmapped chunked
+    prefill) the lanes fold into rows, so each weight tile is still read
+    and masked once per call rather than once per lane; the blocks are
+    chosen for the rows the kernel sees (:func:`_licensed_blocks`)."""
+    k, n = codes.shape[-2:]
+
+    def mm(x, codes, scale, dead, unit):
+        m = int(np.prod(x.shape[:-1]))
+        bm, bk, bn = _licensed_blocks(m, k, n, x.dtype.itemsize)
+        x2 = _pad_to(x.reshape(m, k), (bm, 1))
+        out = _qmm.quant_matmul(x2, codes, scale.reshape(n), dead, unit,
+                                block_m=bm, block_n=bn, block_k=bk,
+                                out_dtype=x.dtype, interpret=interpret)
+        return out[:m].reshape(*x.shape[:-1], n)
+
+    folded = jax.custom_batching.custom_vmap(mm)
+
+    @folded.def_vmap
+    def _fold_lanes(axis_size, in_batched, x, codes, scale, dead, unit):
+        if not any(jax.tree_util.tree_leaves(in_batched[1:])):
+            return folded(x, codes, scale, dead, unit), in_batched[0]
+        axes = tuple(jax.tree_util.tree_map(lambda b: 0 if b else None, ib)
+                     for ib in in_batched)
+        return jax.vmap(mm, in_axes=axes)(x, codes, scale, dead, unit), True
+
+    return folded(x, codes, scale, dead, jnp.asarray(unit, jnp.int32))
 
 
 def pack_intervals(intervals: Sequence[Tuple[float, float]]) -> Tuple[jnp.ndarray, jnp.ndarray]:

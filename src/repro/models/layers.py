@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models.int8 import dense
+
 
 # ---------------------------------------------------------------------- init
 def dense_init(key, in_dim: int, out_dim: int, dtype, scale: Optional[float] = None):
@@ -177,9 +179,9 @@ def _attn_qkv(p: Dict[str, Any], x: jnp.ndarray, cfg,
     (``positions`` broadcastable to (B, S)), so they cannot drift."""
     b, s, _ = x.shape
     h, kh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = jnp.einsum("bsd,df->bsf", x, p["wq"])
-    k = jnp.einsum("bsd,df->bsf", x, p["wk"])
-    v = jnp.einsum("bsd,df->bsf", x, p["wv"])
+    q = dense(x, p["wq"])
+    k = dense(x, p["wk"])
+    v = dense(x, p["wv"])
     if "bq" in p:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
     q = q.reshape(b, s, h, hd)
@@ -304,7 +306,7 @@ def attention_block_paged(
     if quant:
         new_cache["k_scale"] = ksc[None]
         new_cache["v_scale"] = vsc[None]
-    y = jnp.einsum("bsf,fd->bsd", out.reshape(b, 1, h * hd), p["wo"])
+    y = dense(out.reshape(b, 1, h * hd), p["wo"])
     return y, new_cache
 
 
@@ -518,7 +520,7 @@ def attention_block(
         if quant:
             new_cache["k_scale"] = cks
             new_cache["v_scale"] = cvs
-    y = jnp.einsum("bsf,fd->bsd", out.reshape(b, s, h * hd), p["wo"])
+    y = dense(out.reshape(b, s, h * hd), p["wo"])
     return y, new_cache
 
 
@@ -652,11 +654,11 @@ def init_mlp(key, cfg, dtype, d_ff: Optional[int] = None) -> Dict[str, Any]:
 
 def mlp_block(p: Dict[str, Any], x: jnp.ndarray, cfg) -> jnp.ndarray:
     if "w_gate" in p:
-        g = jnp.einsum("bsd,df->bsf", x, p["w_gate"])
-        u = jnp.einsum("bsd,df->bsf", x, p["w_up"])
+        g = dense(x, p["w_gate"])
+        u = dense(x, p["w_up"])
         h = jax.nn.silu(g) * u
     else:
-        u = jnp.einsum("bsd,df->bsf", x, p["w_up"])
+        u = dense(x, p["w_up"])
         r = jax.nn.relu(u)
         h = r * r  # squared ReLU
-    return jnp.einsum("bsf,fd->bsd", h, p["w_down"])
+    return dense(h, p["w_down"])

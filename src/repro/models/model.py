@@ -18,6 +18,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.models import int8 as Q
 from repro.models import layers as L
 from repro.models import moe as M
 from repro.models import rglru as R
@@ -109,6 +110,35 @@ def _apply_block(
     raise ValueError(kind)
 
 
+def _matmul_keys(kind: str, cfg: ModelConfig) -> Tuple[str, ...]:
+    """Sub-trees of a ``kind`` block whose every weight reaches its layer
+    through ``L.dense`` (the GQA projections, the dense MLPs), so int8
+    leaves there stay int8 into the matmul; MLA, MoE, SSM and RG-LRU
+    weights are dequantized before the block runs."""
+    keys = []
+    if kind == "attn" and not cfg.use_mla:
+        keys.append("mixer")
+    if kind == "rec" or (kind == "attn" and not cfg.num_experts):
+        keys.append("ffn")
+    return tuple(keys)
+
+
+def _serve_block(p, kind: str, cfg: ModelConfig, license_intervals,
+                 codes=None, unit=None):
+    return Q.serve_block(p, _matmul_keys(kind, cfg), license_intervals,
+                         cfg.dtype, codes, unit)
+
+
+def fused_weight_share(params: Dict[str, Any], cfg: ModelConfig) -> float:
+    """Share of the int8 weight elements that the matmuls take as codes
+    (``int8.dense``) rather than an eager dequant."""
+    blocks = [(params["units"][f"b{j}"], _matmul_keys(kind, cfg))
+              for j, kind in enumerate(cfg.layer_pattern)]
+    blocks += [(params["tail"][f"t{j}"], _matmul_keys(kind, cfg))
+               for j, kind in enumerate(cfg.tail_pattern)]
+    return Q.fused_weight_share(blocks)
+
+
 def _init_block_cache(cfg: ModelConfig, kind: str, batch: int, capacity: int):
     dtype = cfg.dtype
     if kind == "attn":
@@ -197,9 +227,11 @@ def forward(
     """Returns (logits (B,S,V), aux_loss, new_cache or None).
 
     ``params`` may contain int8 {"codes","scale"} leaves (see
-    serving/quantized.py); they are dequantized INSIDE the layer scan with
-    ``license_intervals`` masks fused in, so weight HBM reads stay int8 and
-    every license tier shares one stored model.
+    models/int8.py); ``license_intervals`` masks them INSIDE the
+    layer scan, so weight HBM reads stay int8 and every license tier
+    shares one stored model.  The GQA and dense-MLP matmuls multiply the
+    masked codes directly; other blocks' leaves are dequantized per unit
+    (``_matmul_keys``).
 
     ``attend_cache=True`` is the *suffix prefill* mode behind the prefix
     cache: ``tokens`` are the uncached tail of a prompt whose positions
@@ -238,17 +270,23 @@ def forward(
 
     pattern = cfg.layer_pattern
     aux_total = jnp.zeros((), jnp.float32)
+    # the matmuls' int8 codes stay whole, out of the scanned xs: each unit
+    # reads its own in place (a scanned slice would be copied first)
+    split = [Q.split_codes(params["units"][f"b{j}"], _matmul_keys(kind, cfg))
+             for j, kind in enumerate(pattern)]
+    whole_codes = [c for c, _ in split]
+    units = {f"b{j}": rest for j, (_, rest) in enumerate(split)}
+    unit_ids = jnp.arange(cfg.pattern_units)
 
     def unit_step(carry, xs):
         x, aux = carry
-        unit_params, unit_cache = xs
-        from repro.serving.quantized import dequant_tree
-
-        unit_params = dequant_tree(unit_params, license_intervals, cfg.dtype)
+        unit_params, unit_cache, unit = xs
         new_caches = {}
         for j, kind in enumerate(pattern):
             c = None if unit_cache is None else unit_cache[f"b{j}"]
-            x, a, nc = _apply_block(unit_params[f"b{j}"], kind, x, cfg,
+            bp = _serve_block(unit_params[f"b{j}"], kind, cfg,
+                              license_intervals, whole_codes[j], unit)
+            x, a, nc = _apply_block(bp, kind, x, cfg,
                                     cache=c, pos=pos,
                                     attend_cache=attend_cache,
                                     chunk_valid=chunk_valid,
@@ -272,12 +310,12 @@ def forward(
 
     if cache is not None:
         (x, aux_total), new_unit_caches = jax.lax.scan(
-            step, (x, aux_total), (params["units"], cache["units"])
+            step, (x, aux_total), (units, cache["units"], unit_ids)
         )
     else:
         (x, aux_total), _ = jax.lax.scan(
-            lambda c, p_: (step(c, (p_, None))[0], ()), (x, aux_total),
-            params["units"],
+            lambda c, xs: (step(c, (xs[0], None, xs[1]))[0], ()),
+            (x, aux_total), (units, unit_ids),
         )
         new_unit_caches = None
 
@@ -287,12 +325,11 @@ def forward(
 
     tail = cfg.tail_pattern
     if tail:
-        from repro.serving.quantized import dequant_tree as _dq
-
         new_tail = {}
         for j, kind in enumerate(tail):
             c = None if cache is None else cache["tail"][f"t{j}"]
-            tp = _dq(params["tail"][f"t{j}"], license_intervals, cfg.dtype)
+            tp = _serve_block(params["tail"][f"t{j}"], kind, cfg,
+                              license_intervals)
             x, a, nc = _apply_block(tp, kind, x, cfg,
                                     cache=c, pos=pos,
                                     attend_cache=attend_cache,

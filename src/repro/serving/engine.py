@@ -98,8 +98,9 @@ def serve_step(params, cfg: ModelConfig, tokens, cache, pos,
                license_intervals=None):
     """ONE decode step: tokens (B,1) + cache at fill-level ``pos``.
 
-    With int8 ``params`` (serving/quantized.py) and ``license_intervals``,
-    this is the fused masked-dequant licensed decode."""
+    With int8 ``params`` (models/int8.py) and ``license_intervals``, this
+    is the licensed decode: the tier's mask applied to the int8 codes
+    inside the layer scan."""
     logits, _, cache = model_lib.forward(params, cfg, tokens, cache=cache,
                                          pos=pos, license_intervals=license_intervals)
     return logits[:, -1], cache
@@ -201,8 +202,9 @@ class ServingEngine:
                  tiers: Optional[Dict[str, LicenseTier]] = None,
                  quantized: bool = False):
         """``quantized=True``: ONE int8 weight store serves all tiers with
-        license masks fused into the in-scan dequant (beyond-paper mode;
-        see serving/quantized.py).  Default is the paper's mask-at-load."""
+        license masks applied to the int8 codes in the layer scan
+        (beyond-paper mode; see serving/quantized.py).  Default is the
+        paper's mask-at-load."""
         self.cfg = cfg
         self.quantized = quantized
         if quantized:

@@ -77,8 +77,8 @@ Licensing integration
 * float path: the view is ``apply_license(base, tier)`` — masking cost
   paid once per (tier, version), cached in :class:`TierViewCache`;
 * int8 path (``quantized=True``): ONE int8 store serves every tier and
-  the view is just the tier's packed license intervals, fused into the
-  in-scan masked dequant (``kernels/masked_dequant`` semantics); with
+  the view is just the tier's live license intervals, applied to the
+  int8 codes inside the layer scan (``models/int8.py``); with
   ``materialize_int8_views=True`` the gateway instead runs the fused
   masked-dequant kernel once per (tier, version) and caches the
   full-precision licensed view — trading memory for per-step speed on
@@ -106,6 +106,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.transport import Transport, TransportError
+from repro.models.model import fused_weight_share
 from repro.serving.engine import (prefill_step, prefill_suffix_step,
                                   right_align, sample, sample_lane,
                                   serve_step, serve_step_paged)
@@ -245,8 +246,8 @@ class LicensedGateway:
         Name -> :class:`LicenseTier`; ``"full"`` is always available.
         Unknown tiers are also resolved against ``server`` when attached.
     quantized:
-        Serve from ONE int8 store with license masks fused into the
-        in-scan dequant (see ``serving/quantized.py``).
+        Serve from ONE int8 store with license masks applied to the
+        int8 codes inside the layer scan (see ``models/int8.py``).
     already_quantized:
         ``params`` is already an int8 store (used by
         ``ServingEngine.gateway()``); implies ``quantized``.
@@ -1537,6 +1538,12 @@ class LicensedGateway:
         out["cache_pool"] = {"paged": self.paged, **self.pool.stats()}
         out["decode_path"] = {"kernel_resident": self.kernel_decode,
                               "pallas": self.decode_pallas}
+        # share of int8 weight elements the matmuls take as codes rather
+        # than an eager dequant (fixed per configuration; materialized
+        # views and float weights serve none)
+        fused = (self.quantized and not self.materialize_int8_views)
+        out["quant"] = {"fused_weight_share": fused_weight_share(
+            self._weights[self.version], self.cfg) if fused else 0.0}
         out["staged_update"] = ({"active": False} if self._stager is None
                                 else {"active": self._stager.active,
                                       **self._stager.stats()})

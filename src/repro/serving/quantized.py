@@ -2,27 +2,32 @@
 
 The paper's licensing masks weights in the DB and ships a *separate* weight
 view per tier (mask-at-load).  Here ONE int8 weight store serves every
-tier: block weights are kept as (codes int8, scale f32) and dequantized
-*inside* the layer scan with the license's magnitude intervals fused into
-the dequant — the semantics of ``kernels/masked_dequant`` (the Pallas
-kernel is the TPU drop-in; the jnp form here lowers through XLA fusion).
+tier: block weights are kept as int8 leaves (codes, f32 scale per output
+column; the format and how a block consumes it live in
+``models/int8.py``), and a tier is only its live magnitude intervals
+(:func:`tier_intervals`), applied to the codes inside the layer scan.
+This module builds the store (:func:`quantize_serving_params`, and
+:func:`requantize_layers` for a staged update), turns a tier into its
+intervals, and materializes a tier's float view once where a deployment
+asks for it (:func:`materialize_licensed_view`, the ``masked_dequant``
+Pallas kernel).
 
 Wins vs mask-at-load:
   * weight HBM reads are int8 — ~2x less than bf16, 4x less than f32;
-  * a new tier costs ZERO extra weight memory (masks are 8 floats);
+  * a new tier costs ZERO extra weight memory (masks are a few floats);
   * the licensed view can't leak: full-precision weights never exist in
     the serving process.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.licensing import LicenseTier
-from repro.kernels.ops import MAX_INTERVALS, pack_intervals
+from repro.models.int8 import is_qleaf
 
 # leaves excluded from quantization (precision- or structure-critical)
 _SKIP = ("norm", "bias", "router", "conv", "A_log", "dt_bias", "D_skip",
@@ -96,40 +101,13 @@ def requantize_layers(qparams: Any, new_flat: Dict[str, Any],
     return jax.tree_util.tree_map_with_path(q, qparams, is_leaf=is_qleaf)
 
 
-def is_qleaf(leaf) -> bool:
-    return isinstance(leaf, dict) and "codes" in leaf and "scale" in leaf
-
-
-def dequant_leaf(leaf, lo: Optional[jnp.ndarray], hi: Optional[jnp.ndarray],
-                 dtype) -> jnp.ndarray:
-    """Fused dequant + license-interval mask (ref semantics of the
-    ``masked_dequant`` Pallas kernel, applied per layer-scan slice)."""
-    if not is_qleaf(leaf):
-        return leaf
-    w = leaf["codes"].astype(jnp.float32) * leaf["scale"]
-    if lo is not None:
-        mag = jnp.abs(w)
-        dead = jnp.zeros(w.shape, bool)
-        for i in range(MAX_INTERVALS):
-            dead = dead | ((mag >= lo[i]) & (mag < hi[i]))
-        w = jnp.where(dead, 0.0, w)
-    return w.astype(dtype)
-
-
-def dequant_tree(tree: Any, license_intervals, dtype) -> Any:
-    lo, hi = (None, None) if license_intervals is None else license_intervals
-    return jax.tree_util.tree_map(
-        lambda l: dequant_leaf(l, lo, hi, dtype), tree, is_leaf=is_qleaf
-    )
-
-
 def materialize_licensed_view(qparams: Any, tier: Optional[LicenseTier],
                               dtype) -> Any:
     """Run the fused masked-dequant ONCE, returning a full-precision
     licensed view of the int8 store.
 
     This is the gateway's ``materialize_int8_views`` path: a long decode
-    stream re-pays the in-scan dequant every step, so for hot tiers it
+    stream re-pays the in-scan mask every step, so for hot tiers it
     can be cheaper to burn the HBM for a materialized view amortized
     across the whole (tier, version) lifetime.  2-D weight slices go
     through ``kernels.ops.masked_dequant`` (the Pallas kernel on TPU,
@@ -162,18 +140,40 @@ def materialize_licensed_view(qparams: Any, tier: Optional[LicenseTier],
     return jax.tree_util.tree_map(dq, qparams, is_leaf=is_qleaf)
 
 
-def tier_intervals(tier: Optional[LicenseTier]) -> Optional[Tuple[jnp.ndarray, jnp.ndarray]]:
-    """Pack a tier's '*'-pattern intervals for the fused dequant path.
+def live_intervals(intervals: Sequence[Tuple[float, float]]
+                   ) -> List[Tuple[float, float]]:
+    """A tier's intervals as f32 bounds, empty ones dropped, sorted and
+    merged where they overlap or touch: the same set of magnitudes, in as
+    few ``[lo, hi)`` tests as it takes."""
+    out: List[Tuple[float, float]] = []
+    for lo, hi in sorted((float(np.float32(a)), float(np.float32(b)))
+                         for a, b in intervals):
+        if not hi > lo:
+            continue
+        if out and lo <= out[-1][1]:
+            out[-1] = (out[-1][0], max(out[-1][1], hi))
+        else:
+            out.append((lo, hi))
+    return out
 
-    The in-scan dequant applies one global interval set (per-layer patterns
-    would need per-unit interval tensors — supported by stacking, omitted
-    for brevity); '*' tiers are the common production case."""
+
+def tier_intervals(tier: Optional[LicenseTier]) -> Optional[Tuple[jnp.ndarray, jnp.ndarray]]:
+    """A tier's live intervals as (lo, hi) f32 arrays of shape (n,), or
+    None when nothing is masked.
+
+    The compiled step specializes on ``n``: the full tier (None) runs with
+    no mask, and tiers with the same count of live intervals share one
+    program.  The in-scan mask applies one global interval set (per-layer
+    patterns would need per-unit interval tensors — supported by stacking,
+    omitted for brevity); '*' tiers are the common production case."""
     if tier is None or not tier.masks:
         return None
     ivs = list(tier.masks.get("*", ()))
     for pat, v in tier.masks.items():
         if pat != "*":
             ivs.extend(v)
-    if not ivs:
+    live = live_intervals(ivs)
+    if not live:
         return None
-    return pack_intervals(ivs)
+    lo, hi = (np.asarray(col, np.float32) for col in zip(*live))
+    return jnp.asarray(lo), jnp.asarray(hi)

@@ -364,6 +364,7 @@ GATEWAY_METRICS_KEYS: Tuple[str, ...] = (
     "view_cache.invalidations", "view_cache.entries",
     "oldest_wait_s", "queue_wait_by_tier.*", "tenants.*",
     "cache_pool.*", "decode_path.kernel_resident", "decode_path.pallas",
+    "quant.fused_weight_share",
     "staged_update.*", "lease.*",
     "chunked_prefill.enabled", "chunked_prefill.chunk_size",
     "chunked_prefill.chunks",

@@ -23,6 +23,8 @@ def rng(seed=0):
 
 
 # --------------------------------------------------------------- quant_matmul
+# ``ops.licensed_matmul`` unmasked on a 2-D weight: the kernel's plain
+# int8 matmul, against the oracle
 @pytest.mark.parametrize("m,k,n", [(128, 512, 128), (256, 512, 256), (128, 1024, 384), (8, 512, 128)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_quant_matmul_matches_ref(m, k, n, dtype):
@@ -30,17 +32,18 @@ def test_quant_matmul_matches_ref(m, k, n, dtype):
     x = jnp.asarray(r.standard_normal((m, k)), dtype=dtype)
     codes = jnp.asarray(r.integers(-127, 128, (k, n)), dtype=jnp.int8)
     scale = jnp.asarray(np.abs(r.standard_normal(n)) * 0.02 + 1e-4, dtype=jnp.float32)
-    got = ops.quant_matmul(x, codes, scale, out_dtype=jnp.float32, interpret=True)
+    got = ops.licensed_matmul(x, codes, scale, interpret=True)
     want = ref.quant_matmul(x, codes, scale, jnp.float32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-2, atol=2e-2)
 
 
 def test_quant_matmul_unaligned_shapes_pad():
+    # no multiple of 128 divides K or N: each is one whole block
     r = rng(3)
     x = jnp.asarray(r.standard_normal((130, 700)), dtype=jnp.float32)
     codes = jnp.asarray(r.integers(-127, 128, (700, 200)), dtype=jnp.int8)
     scale = jnp.asarray(np.abs(r.standard_normal(200)) + 0.01, dtype=jnp.float32)
-    got = ops.quant_matmul(x, codes, scale, interpret=True)
+    got = ops.licensed_matmul(x, codes, scale, interpret=True)
     want = ref.quant_matmul(x, codes, scale, jnp.float32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-2, atol=1e-2)
 
@@ -50,7 +53,7 @@ def test_quant_matmul_batched_leading_dims():
     x = jnp.asarray(r.standard_normal((4, 64, 512)), dtype=jnp.float32)
     codes = jnp.asarray(r.integers(-127, 128, (512, 128)), dtype=jnp.int8)
     scale = jnp.ones(128, jnp.float32) * 0.02
-    got = ops.quant_matmul(x, codes, scale, interpret=True)
+    got = ops.licensed_matmul(x, codes, scale, interpret=True)
     assert got.shape == (4, 64, 128)
     want = ref.quant_matmul(x.reshape(-1, 512), codes, scale, jnp.float32).reshape(4, 64, 128)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-3, atol=1e-3)
@@ -174,6 +177,74 @@ def test_quant_matmul_property(m, k, n, seed):
     x = jnp.asarray(r.standard_normal((m, k)), dtype=jnp.float32)
     codes = jnp.asarray(r.integers(-127, 128, (k, n)), dtype=jnp.int8)
     scale = jnp.asarray(np.abs(r.standard_normal(n)) * 0.05 + 1e-4, jnp.float32)
-    got = ops.quant_matmul(x, codes, scale, interpret=True)
+    got = ops.licensed_matmul(x, codes, scale, interpret=True)
     want = ref.quant_matmul(x, codes, scale, jnp.float32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-3, atol=1e-3)
+
+
+# ------------------------------------------------------------ licensed_matmul
+def _licensed_case(r, u, k, n, rows):
+    from repro.models.int8 import code_bounds
+
+    x = jnp.asarray(r.standard_normal((*rows, k)), jnp.bfloat16)
+    codes = jnp.asarray(r.integers(-127, 128, (u, k, n)), jnp.int8)
+    scale = jnp.asarray(r.uniform(1e-3, 3e-3, (u, 1, n)), jnp.float32)
+    lo = jnp.asarray([0.0, 0.15], jnp.float32)
+    hi = jnp.asarray([0.05, 0.2], jnp.float32)
+    dead = [tuple(d.reshape(2, n) for d in code_bounds(scale[i], lo, hi))
+            for i in range(u)]
+    return x, codes, scale, dead
+
+
+def _jnp_licensed(x, codes, scale, dead):
+    from repro.models.int8 import dense
+
+    return dense(x, {"codes": codes, "scale": scale, **(
+        {} if dead is None else {"dead": dead})})
+
+
+@pytest.mark.parametrize("rows", [(8,), (1,), (2, 256), (3, 40)])
+@pytest.mark.parametrize("masked", [True, False])
+def test_licensed_matmul_matches_jnp_form(rows, masked):
+    """The Pallas body (interpret mode) == the helper's jax.numpy form, for
+    each unit of a stacked weight read in place, masked or not."""
+    r = rng(17 + len(rows))
+    x, codes, scale, dead = _licensed_case(r, 3, 256, 384, rows)
+    for u in range(3):
+        d = dead[u] if masked else None
+        got = ops.licensed_matmul(x, codes, scale[u], d, u, interpret=True)
+        want = _jnp_licensed(x, codes[u], scale[u], d)
+        assert got.shape == (*rows, 384) and got.dtype == x.dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=1e-2, atol=1e-2)
+
+
+def test_licensed_matmul_folds_vmapped_lanes():
+    """vmap over the activation alone (the lane-vmapped chunked prefill)
+    folds lanes into rows: same result as a per-lane loop."""
+    r = rng(23)
+    x, codes, scale, dead = _licensed_case(r, 2, 256, 256, (4, 16))
+    f = lambda xl: ops.licensed_matmul(xl, codes, scale[1], dead[1], 1,
+                                       interpret=True)
+    got = jax.vmap(f)(x)
+    want = jnp.stack([f(x[i]) for i in range(4)])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # a batched weight is not folded: each lane reads its own codes
+    stacks = jnp.stack([codes, codes[::-1]])
+    g = lambda xl, cl: ops.licensed_matmul(xl, cl, scale[1], dead[1], 1,
+                                           interpret=True)
+    got = jax.vmap(g)(x[:2], stacks)
+    want = jnp.stack([g(x[i], stacks[i]) for i in range(2)])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_licensed_blocks_tile_weights_exactly():
+    for m, k, n in [(8, 2048, 11008), (2048, 11008, 2048), (2048, 2048, 256),
+                    (1, 64, 96), (5000, 2048, 2048)]:
+        bm, bk, bn = ops._licensed_blocks(m, k, n, 2)
+        assert k % bk == 0 and n % bn == 0 and bk * bn <= 1 << 20
+        assert bm == m or bm == 512
+    # a prime width has no tile under 1M codes: refused at trace time
+    with pytest.raises(ValueError):
+        ops._licensed_blocks(8, 1031, 1031, 2)

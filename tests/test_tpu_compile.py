@@ -88,3 +88,24 @@ def test_masked_dequant_compiles(spec):
 def test_delta_apply_compiles(spec, kernel, n_delta):
     _compile(kernel, spec((262_144,), jnp.bfloat16), spec((n_delta,), jnp.int32),
              spec((n_delta,), jnp.bfloat16))
+
+
+# the licensed matmul at every qwen2.5-3b unit matrix, stacked over its 36
+# units and read in place: decode rows (8) and a full 256-row chunk of
+# 8 lanes (2048), with one masked interval and unmasked
+@pytest.mark.parametrize("rows", [8, 2048])
+@pytest.mark.parametrize("k,n", [
+    (CFG.d_model, HEADS * HEAD_DIM),          # wq, wo
+    (CFG.d_model, KV_HEADS * HEAD_DIM),       # wk, wv
+    (CFG.d_model, CFG.d_ff),                  # w_gate, w_up
+    (CFG.d_ff, CFG.d_model),                  # w_down
+])
+@pytest.mark.parametrize("masked", [True, False])
+def test_licensed_matmul_compiles(spec, rows, k, n, masked):
+    from repro.kernels import ops
+
+    dead = (spec((1, n), jnp.int32),) * 2 if masked else None
+    _compile(lambda x, c, s, d, u: ops.licensed_matmul(x, c, s, d, u),
+             spec((rows, k), jnp.bfloat16),
+             spec((CFG.num_layers, k, n), jnp.int8),
+             spec((1, n), jnp.float32), dead, spec((), jnp.int32))
