@@ -13,6 +13,9 @@ lives in a file of its own, found by the name ``BENCHMARK.json`` gives:
 Shared yardstick code: ``weights.py`` (seeded weights, also regenerated
 by the reference), ``client.py`` (open-loop client and end-to-end
 reductions), ``trace.py`` (profiler-trace reduction), ``costs.py``
-(operations and bytes from shapes, peaks table), ``check.py`` (the
-comparison that decides ``correct``).
+(operations and bytes from a configuration file, peaks table),
+``widths.py`` (the served layout against the file's widths),
+``check.py`` (the comparison that decides ``correct``).
+``configmap.py`` maps a file to the program's ``ModelConfig`` where the
+program has no mapping of its own.
 """

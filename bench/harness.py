@@ -109,23 +109,52 @@ class CompileCounter:
 
 
 # ------------------------------------------------------------ the program
-def model_config(cfg: Dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
+class MappingMismatch(ValueError):
+    """The program maps a file to another model than ``bench/configmap.py``
+    does."""
 
-    act = cfg["hidden_act"]
-    return ModelConfig(
-        name=cfg["name"], arch_type="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        vocab_size=cfg["vocab_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"],
-        mlp_type={"silu": "swiglu", "relu2": "squared_relu"}[act],
-        attn_bias=bool(cfg["attention_bias"]),
-        norm_layernorm=bool(cfg.get("layer_norm_eps")),
-        rope_theta=float(cfg["rope_theta"]), dtype_name="bfloat16",
-        remat=False, source=cfg["source"])
+
+def program_mapping() -> Optional[Callable]:
+    """The program's own mapping of a configuration file
+    (``repro.configs.from_file.model_config``), or None where it has
+    none."""
+    sys.path.insert(0, str(ROOT / "src"))
+    if importlib.util.find_spec("repro.configs.from_file") is None:
+        return None
+    from repro.configs.from_file import model_config as program_map
+
+    return program_map
+
+
+def model_config(cfg: Dict):
+    """The program's ``ModelConfig`` for a configuration file, with the
+    serving choices: bf16, no remat, the file's name and source.  The
+    program maps the file where it has a mapping of its own; else
+    ``bench/configmap.py`` maps what it serves.  Either refuses, by name,
+    every key it cannot honour.  Where both map the file, every field
+    ``configmap`` sets has to agree, or ``MappingMismatch`` names those
+    that differ."""
+    from bench import configmap
+
+    program_map = program_mapping()
+    if program_map is None:
+        mcfg = configmap.model_config(cfg)
+    else:
+        mcfg = program_map(cfg)
+        try:
+            ours = configmap.fields(cfg)
+        except configmap.UnsupportedConfig:
+            ours = {}                  # a mechanism only the program maps
+        differ = {k: (getattr(mcfg, k, None), v) for k, v in ours.items()
+                  if getattr(mcfg, k, None) != v}
+        if differ:
+            raise MappingMismatch(
+                f"configuration {cfg.get('name', '?')!r}: the program maps "
+                + "; ".join(f"{k} to {p!r}, bench/configmap.py to {b!r}"
+                            for k, (p, b) in sorted(differ.items())))
+    return mcfg.replace(
+        name=cfg["name"], dtype_name="bfloat16", remat=False,
+        source=cfg["source"])
 
 
 def served_layout(mcfg):
@@ -247,21 +276,27 @@ class Setup:
 def set_up(workload: str, seed: int, *, require_chip: bool = True,
            spec: Optional[Dict] = None, mix: Optional[Dict] = None,
            compile_cache: bool = True, log=print) -> Setup:
-    """Device check, compile cache, weights from the seed, warm-up."""
+    """Device check, the program's mapping of the file and its served
+    layout checked against it, compile cache, weights from the seed,
+    warm-up."""
     import jax
 
+    from bench import widths
     from bench import traffic, weights
 
     spec = spec or cell_spec(workload)
     cell, cfg = spec["cell"], spec["config"]
     dev = (device_or_fail(cell["chips"]) if require_chip
            else jax.devices()[0])
+    # what the program cannot serve ends the run here, before any array
+    mcfg = model_config(cfg)
+    layout = served_layout(mcfg)
+    widths.check(cfg, weights.layer_names(layout), mcfg.replace(
+        vocab_size=cfg["vocab_size"]).padded_vocab,
+        getattr(mcfg, "experts_per_token", None))
     cache_dir = use_compile_cache() if compile_cache else None
     counter = CompileCounter()
     mix = mix or traffic.load_mix(cell["traffic"])
-    sys.path.insert(0, str(ROOT / "src"))
-    mcfg = model_config(cfg)
-    layout = served_layout(mcfg)
     t0 = time.perf_counter()
     params = weights.build(layout, seed,
                            tied=bool(cfg["tie_word_embeddings"]))

@@ -58,3 +58,20 @@ def test_lead_in_counts_only_inside_the_window():
     assert sorted(c.itl_ms()) == pytest.approx([500.0, 500.0])
     assert c.tokens_in_window() == 4
     assert c.finished_tokens() == {"full": 6}
+
+
+def test_itl_mean_reader_takes_the_gaps_itl_p95_ranks():
+    from types import SimpleNamespace
+
+    from bench.harness import reader
+
+    c = Client(gw=None, requests=[])
+    c.t0, c.end, c.closed = 0.0, 10.0, 12.0
+    c.tracked = [_tracked(1.0, [1.5, 1.75, 2.25]),
+                 _tracked(2.0, [4.0, 9.0, 11.0])]     # last token after end
+    run = SimpleNamespace(client=c)
+    assert reader("sched.itl_mean_ms")(run) == pytest.approx(
+        (250.0 + 500.0 + 5000.0) / 3)
+    assert reader("itl_p95_ms")(run) == pytest.approx(5000.0)
+    c.tracked = [_tracked(1.0, [1.5])]
+    assert reader("sched.itl_mean_ms")(run) is None

@@ -4,7 +4,9 @@ metric arithmetic and the comparison, with the chip check skipped.
 Two toy architectures cover both block kinds the reference holds:
 SwiGLU with RMSNorm, QKV bias, a tied head and three tiers (the qwen
 cell's shape), and squared ReLU with LayerNorm and an untied head (a
-Nemotron-4 block).
+Nemotron-4 block).  A third, latent attention with fine-grained experts
+(a DeepSeek-V2 block), is given only as a config dict and a mix and goes
+through set-up and serving: no file of the harness names it.
 
 It also holds the comparison's two controls at a size a test run can
 hold: a run with the int4 control in the program's place comes out not
@@ -17,7 +19,7 @@ import time
 
 import pytest
 
-from bench import harness
+from bench import harness, traffic
 
 E2E = ["itl_p95_ms", "output_tok_s", "setup_s"]
 LIMIT = 0.05
@@ -74,6 +76,33 @@ TOYS = {
              output_tokens={"dist": "lognormal", "median": 8,
                             "sigma": 0.25, "min": 4, "max": 16})),
 }
+
+
+MLA_MOE = (
+    {"name": "toy-mla-moe", "source": "test", "hidden_act": "silu",
+     "hidden_size": 64, "intermediate_size": 160, "num_hidden_layers": 2,
+     "num_attention_heads": 4, "num_key_value_heads": 4, "vocab_size": 512,
+     "rope_theta": 10000.0, "rope_scaling": None, "rms_norm_eps": 1e-6,
+     "tie_word_embeddings": False, "attention_bias": False,
+     "kv_lora_rank": 32, "q_lora_rank": None, "qk_nope_head_dim": 16,
+     "qk_rope_head_dim": 8, "v_head_dim": 16, "n_routed_experts": 8,
+     "num_experts_per_tok": 2, "moe_intermediate_size": 32,
+     "n_shared_experts": 1, "norm_topk_prob": False,
+     "scoring_func": "softmax", "topk_method": "greedy",
+     "first_k_dense_replace": 0, "moe_layer_freq": 1,
+     "serving": {"tiers": {"full": {}, "pro": {"*": [[0.0, 0.02]]}},
+                 "gateway": {"max_batch": 2, "max_lanes": 4,
+                             "block_size": 32, "num_blocks": 16,
+                             "max_prompt": 60, "max_new_cap": 8,
+                             "chunk_size": 32}},
+     "check": {"logit_gap_limit": LIMIT, "min_served_tokens_per_tier": 8}},
+    _mix(arrival={"process": "poisson", "rate_per_s": 4},
+         tiers={"full": 0.5, "pro": 0.5},
+         prompt_tokens={"dist": "lognormal", "median": 30, "sigma": 0.5,
+                        "min": 8, "max": 60},
+         output_tokens={"dist": "lognormal", "median": 4, "sigma": 0.5,
+                        "min": 2, "max": 8},
+         max_context=64))
 
 
 def _run(toy, seed, *, control=False, on_gateway=None):
@@ -142,3 +171,29 @@ def test_altered_tokens_come_out_not_correct():
     out = _run(toy, 3_000_000_001, on_gateway=alter)
     assert out["correct"] is False
     assert out["checks"]["logit_gap"]["value"] > LIMIT
+
+
+def test_mla_moe_toy_is_served_from_a_config_dict_alone():
+    """Weights from the seed for every leaf kind (expert stacks, router,
+    latent projections and norm), the gateway's warm-up, and requests of
+    two tiers through the program's MoE path.  There is no MoE reference
+    yet, so this toy stops before the comparison."""
+    cfg, mix = MLA_MOE
+    seed = 3_000_000_001
+    spec = {"cell": {"name": "mla_moe", "chips": 1, "traffic": "mla_moe"},
+            "config": cfg}
+    su = harness.set_up("mla_moe", seed, require_chip=False, spec=spec,
+                        mix=mix, compile_cache=False, log=lambda s: None)
+    assert su.mcfg.use_mla and su.mcfg.num_experts == 8
+    assert su.info["warm"]["warm_requests"] > 0
+    warm = su.counter.n
+    gw = harness.make_gateway(su.mcfg, su.params, cfg)
+    reqs = [(r, gw.submit(r.prompt, license=r.tier,
+                          max_new_tokens=r.max_new_tokens))
+            for r in traffic.generate(mix, seed, 1.0, cfg["vocab_size"])]
+    gw.run()
+    assert {r.tier for r, _ in reqs} == {"full", "pro"}
+    for r, served in reqs:
+        assert served.error is None
+        assert len(served.out_tokens) == r.max_new_tokens
+    assert su.counter.n == warm, su.counter.names[warm:]

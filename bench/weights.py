@@ -1,13 +1,14 @@
 """Seeded weights, made on the device in the type they are served in.
 
-Every value is an exact function of (seed, leaf name, layer): integer
+Every value is an exact function of (seed, leaf name, index): integer
 codes come from threefry bits through integer arithmetic only, and each
 float is one rounding of an integer times a constant.  So the harness
 (which builds the whole served tree in one jitted call) and the plain
-reference (which regenerates one layer at a time, long after the served
+reference (which regenerates one matrix at a time, long after the served
 tree is gone) see bit-identical weights without sharing any array.
 
-Leaf kinds, by name:
+Leaf kinds, by name; a leaf may carry leading axes (layer, then
+expert), and each index tuple of them is one matrix of its own:
 
 * ``.../codes`` + ``.../scale``: an int8 matrix of the serving store,
   per-output-channel scales, dequantized as ``codes * scale`` with the
@@ -15,8 +16,13 @@ Leaf kinds, by name:
   (``1/sqrt(fan_in)``);
 * ``embed/tok``, ``lm_head``: bf16, std 0.02 (``lm_head`` is ``tok.T``
   when the configuration ties them);
+* ``router``: a float matrix the store keeps float, std 0.02 (the
+  program's ``init_moe``);
 * attention biases ``bq``/``bk``/``bv``: bf16, std 0.02;
-* norm scales: ones; norm biases: zeros (``init_norm``).
+* norm scales (``norm_scale``, ``*_norm``): ones; norm biases: zeros
+  (``init_norm``).
+
+Any other leaf is an error.
 """
 from __future__ import annotations
 
@@ -31,6 +37,7 @@ import numpy as np
 CODE_STD = 36.95
 EMBED_STD = 0.02
 BIAS_STD = 0.02
+ROUTER_STD = 0.02
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -65,11 +72,20 @@ def float_leaf(key: jax.Array, shape, std: float, dtype) -> jax.Array:
             * np.float32(std / CODE_STD)).astype(dtype)
 
 
-def qmatrix(key: jax.Array, name: str, layer, shape: Tuple[int, int]
+def index_key(key: jax.Array, name: str, index) -> jax.Array:
+    """The key of one matrix of leaf ``name``: ``index`` is its layer, or
+    a tuple (layer, expert, ..) of its leading axes."""
+    k = leaf_key(key, name)
+    for i in (index if isinstance(index, tuple) else (index,)):
+        k = jax.random.fold_in(k, i)
+    return k
+
+
+def qmatrix(key: jax.Array, name: str, index, shape: Tuple[int, int]
             ) -> Tuple[jax.Array, jax.Array]:
-    """One layer's (codes int8 (in, out), scale f32 (1, out)) of the
-    stacked int8 leaf ``name`` (its path without ``/codes``)."""
-    k = jax.random.fold_in(leaf_key(key, name), layer)
+    """One matrix's (codes int8 (in, out), scale f32 (1, out)) of the
+    int8 leaf ``name`` (its path without ``/codes``) at ``index``."""
+    k = index_key(key, name, index)
     c = codes(jax.random.fold_in(k, 0), shape).astype(jnp.int8)
     s = channel_scale(jax.random.fold_in(k, 1), shape[0], shape[1])
     return c, s
@@ -80,9 +96,13 @@ def dequant(c: jax.Array, s: jax.Array) -> jax.Array:
     return c.astype(jnp.float32) * s
 
 
-def bias(key: jax.Array, name: str, layer, n: int, dtype) -> jax.Array:
-    k = jax.random.fold_in(leaf_key(key, name), layer)
-    return float_leaf(k, (n,), BIAS_STD, dtype)
+def bias(key: jax.Array, name: str, index, n: int, dtype) -> jax.Array:
+    return float_leaf(index_key(key, name, index), (n,), BIAS_STD, dtype)
+
+
+def router(key: jax.Array, name: str, index, shape: Tuple[int, int],
+           dtype) -> jax.Array:
+    return float_leaf(index_key(key, name, index), shape, ROUTER_STD, dtype)
 
 
 def embedding(key: jax.Array, shape: Tuple[int, int], dtype) -> jax.Array:
@@ -100,10 +120,20 @@ def _path(path) -> str:
     return "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
 
 
+def _stacked(make, lead: Tuple[int, ...]) -> jax.Array:
+    """``make(index)`` for every index tuple of the leading axes ``lead``,
+    stacked; a leaf with none is index 0, as one layer."""
+    if not lead:
+        return make((0,))
+    idx = jnp.indices(lead).reshape(len(lead), -1).T
+    out = jax.vmap(lambda i: make(tuple(i)))(idx)
+    return out.reshape(lead + out.shape[1:])
+
+
 def build(shapes: Any, seed: int, *, tied: bool) -> Any:
     """The whole served tree for the shape tree ``shapes`` (leaves with
     ``.shape``/``.dtype``), built on the default device in ONE jitted
-    call.  Stacked unit leaves carry the layer axis first."""
+    call.  Leading axes (layer, expert) come first."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     names = [_path(p) for p, _ in flat]
     specs = [(tuple(x.shape), jnp.dtype(x.dtype)) for _, x in flat]
@@ -115,26 +145,22 @@ def build(shapes: Any, seed: int, *, tied: bool) -> Any:
             return embedding(key, shape, dtype)
         if name == "lm_head":
             return head(key, shape, tied, dtype)
-        if short[-1] == "norm_scale":
+        if short[-1] == "norm_scale" or short[-1].endswith("_norm"):
             return jnp.ones(shape, dtype)
         if short[-1] == "bias" and "norm" in short[-2]:
             return jnp.zeros(shape, dtype)
-        stacked = short[0] == "units"
-        n_layers = shape[0] if stacked else None
         if short[-1] in ("codes", "scale"):
             base = "/".join(short[:-1])
             cshape = by_name[base + "/codes"][0]
-            mat = cshape[-2:]
-            idx = 0 if short[-1] == "codes" else 1
-            if stacked:
-                return jax.vmap(lambda i: qmatrix(key, base, i, mat)[idx])(
-                    jnp.arange(n_layers))
-            return qmatrix(key, base, 0, mat)[idx]
+            which = 0 if short[-1] == "codes" else 1
+            return _stacked(lambda i: qmatrix(key, base, i, cshape[-2:])[which],
+                            cshape[:-2])
         if short[-1] in ("bq", "bk", "bv"):
-            if stacked:
-                return jax.vmap(lambda i: bias(key, name, i, shape[-1],
-                                               dtype))(jnp.arange(n_layers))
-            return bias(key, name, 0, shape[-1], dtype)
+            return _stacked(lambda i: bias(key, name, i, shape[-1], dtype),
+                            shape[:-1])
+        if short[-1] == "router":
+            return _stacked(lambda i: router(key, name, i, shape[-2:], dtype),
+                            shape[:-2])
         raise ValueError(f"no generator for weight leaf {name!r} {shape}")
 
     @jax.jit
