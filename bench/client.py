@@ -9,6 +9,13 @@ behind it; ``lateness`` reports how late the client submitted.  Requests
 due before the window (the traffic's lead-in) are served like any other,
 so the window opens on a loaded server; the window's reductions count
 what happened inside it.
+
+With ``record_steps`` on, each step that served requests leaves a
+``StepRecord``.  Its ``counters`` are the program's count of that step's
+work: a gateway that counts per-step work (rows routed, experts
+touched, ..) puts a flat dict of numbers on the ``ScheduledAction`` that
+``step()`` returns, as ``counters``; a gateway that counts none leaves
+the record's dict empty.
 """
 from __future__ import annotations
 
@@ -66,6 +73,8 @@ class StepRecord:
     # prefill: per lane, (first position, tokens) of the chunk
     chunk: List[tuple] = field(default_factory=list)
     new_tokens: int = 0
+    # the program's own count of the step's work (module docstring)
+    counters: Dict[str, float] = field(default_factory=dict)
 
 
 class Client:
@@ -137,7 +146,8 @@ class Client:
         self.live = still
         if before is not None and act is not None and act.requests:
             rec = StepRecord(act.kind, len(act.requests), t_start, t,
-                             new_tokens=new)
+                             new_tokens=new, counters=dict(
+                                 getattr(act, "counters", None) or {}))
             if act.kind == "decode":
                 # each member decoded at its pre-step position pos - 1
                 rec.ctx = [int(r.pos) for r in act.requests]

@@ -28,6 +28,8 @@ from __future__ import annotations
 import inspect
 from typing import Any, Dict
 
+from bench.harness import UnsupportedConfig
+
 # keys that change nothing the program computes when served
 NEUTRAL = frozenset({
     "name", "source", "architectures", "model_type", "torch_dtype",
@@ -38,16 +40,6 @@ NEUTRAL = frozenset({
     "reduced", "assumed", "departures", "deployment", "serving", "check",
     "reference", "published",
 })
-
-
-class UnsupportedConfig(ValueError):
-    """Keys of a configuration file that the program cannot honour."""
-
-    def __init__(self, name: str, problems: Dict[str, str]):
-        self.keys = sorted(problems)
-        super().__init__(
-            f"configuration {name!r}: the program cannot honour "
-            + "; ".join(f"{k} ({problems[k]})" for k in self.keys))
 
 
 def _default(fn, arg: str):
@@ -165,5 +157,8 @@ def fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
     for key in sorted(set(cfg) - seen):
         bad[key] = "unknown to the program"
     if bad:
-        raise UnsupportedConfig(cfg.get("name", "?"), bad)
+        keys = sorted(bad)
+        raise UnsupportedConfig(
+            f"configuration {cfg.get('name', '?')!r}: the program cannot "
+            "honour " + "; ".join(f"{k} ({bad[k]})" for k in keys), keys)
     return kw

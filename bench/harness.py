@@ -109,6 +109,16 @@ class CompileCounter:
 
 
 # ------------------------------------------------------------ the program
+class UnsupportedConfig(ValueError):
+    """A configuration file the program cannot serve, whichever mapping
+    refused it: ``keys`` names what it cannot honour, empty where the
+    refusal named nothing."""
+
+    def __init__(self, message: str, keys=()):
+        super().__init__(message)
+        self.keys = list(keys)
+
+
 class MappingMismatch(ValueError):
     """The program maps a file to another model than ``bench/configmap.py``
     does."""
@@ -128,22 +138,32 @@ def program_mapping() -> Optional[Callable]:
 
 def model_config(cfg: Dict):
     """The program's ``ModelConfig`` for a configuration file, with the
-    serving choices: bf16, no remat, the file's name and source.  The
-    program maps the file where it has a mapping of its own; else
-    ``bench/configmap.py`` maps what it serves.  Either refuses, by name,
-    every key it cannot honour.  Where both map the file, every field
-    ``configmap`` sets has to agree, or ``MappingMismatch`` names those
-    that differ."""
+    serving choices: bf16, no remat, the file's name and source.
+
+    The program maps the file where it has a mapping of its own; else
+    ``bench/configmap.py`` maps what it serves.  The program's contract:
+    ``model_config(cfg)`` returns a ``ModelConfig``, or raises a
+    ``ValueError`` whose ``keys`` attribute names every key of ``cfg``
+    it cannot honour.  Either mapping's refusal comes out of here as
+    ``UnsupportedConfig``, chained to the program's own exception.
+    Where both map the file, every field ``configmap`` sets has to agree,
+    or ``MappingMismatch`` names those that differ."""
     from bench import configmap
 
     program_map = program_mapping()
     if program_map is None:
         mcfg = configmap.model_config(cfg)
     else:
-        mcfg = program_map(cfg)
+        try:
+            mcfg = program_map(cfg)
+        except ValueError as e:
+            keys = list(getattr(e, "keys", None) or ())
+            raise UnsupportedConfig(
+                f"configuration {cfg.get('name', '?')!r}: the program "
+                f"cannot honour {', '.join(keys) or 'it'} ({e})", keys) from e
         try:
             ours = configmap.fields(cfg)
-        except configmap.UnsupportedConfig:
+        except UnsupportedConfig:
             ours = {}                  # a mechanism only the program maps
         differ = {k: (getattr(mcfg, k, None), v) for k, v in ours.items()
                   if getattr(mcfg, k, None) != v}

@@ -4,7 +4,11 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 From the root of a checkout.  Needs an accelerator: with none (or fewer
-chips than the cell asks for) it exits 3 and prints no result.  The last
+chips than the cell asks for) it exits 3 and prints no result.  A
+configuration the program cannot serve (a key it cannot honour, a
+mapping that differs from ``bench/configmap.py``'s, a served layout
+that differs from the file's widths) ends set-up before any weight is
+made: one line ``bench: <why>`` on standard error, exit 1, no result.  The last
 line of standard output is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics, or with
 ``--trace 1`` its per-layer metrics), ``device``, with ``--trace 1``
@@ -44,7 +48,7 @@ def main(argv=None) -> int:
                     help="comma-separated rates: one window each, one set-up")
     args = ap.parse_args(argv)
 
-    from bench import harness
+    from bench import harness, widths
 
     if args.sweep:
         rates = [float(r) for r in args.sweep.split(",")]
@@ -59,6 +63,10 @@ def main(argv=None) -> int:
     except harness.NoChip as e:
         print(f"bench: {e}", file=sys.stderr)
         return 3
+    except (harness.UnsupportedConfig, harness.MappingMismatch,
+            widths.LayoutMismatch) as e:
+        print(f"bench: {e}", file=sys.stderr)
+        return 1
     print(json.dumps(out), flush=True)
     return 0
 

@@ -1,4 +1,5 @@
-"""Exact percentiles and the client's reductions from token timestamps."""
+"""Exact percentiles, the client's reductions from token timestamps, and
+the per-step records of a traced window."""
 import math
 
 import numpy as np
@@ -75,3 +76,58 @@ def test_itl_mean_reader_takes_the_gaps_itl_p95_ranks():
     assert reader("itl_p95_ms")(run) == pytest.approx(5000.0)
     c.tracked = [_tracked(1.0, [1.5])]
     assert reader("sched.itl_mean_ms")(run) is None
+
+
+class _Lane:
+    def __init__(self, n):
+        self.out_tokens, self.max_new_tokens = [], n
+        self.error, self.pos, self.cursor = None, 5, 0
+
+
+class _Gateway:
+    """Emits one token to every lane on each step and returns ``act``."""
+
+    sync_active = False
+
+    def __init__(self, make_act):
+        self.make_act = make_act
+        self.lanes = []
+
+    def submit(self, prompt, license, max_new_tokens):
+        lane = _Lane(max_new_tokens)
+        self.lanes.append(lane)
+        return lane
+
+    def step(self):
+        live = [r for r in self.lanes
+                if len(r.out_tokens) < r.max_new_tokens]
+        for r in live:
+            r.out_tokens.append(1)
+        return self.make_act(live) if live else None
+
+
+def _recorded(make_act):
+    reqs = [Request(0.0, "full", np.zeros(4, np.int32), 3)]
+    c = Client(_Gateway(make_act), reqs)
+    c.record_steps = True
+    c.run(c.clock(), 0.05, drain_s=1.0)
+    assert c.steps and all(s.kind == "decode" for s in c.steps)
+    return c.steps
+
+
+def test_step_records_carry_the_actions_counters():
+    from types import SimpleNamespace
+
+    counters = {"rows_routed": 96.0, "experts_touched": 12}
+    steps = _recorded(lambda live: SimpleNamespace(
+        kind="decode", requests=live, counters=counters))
+    assert [s.counters for s in steps] == [counters] * len(steps)
+    assert steps[0].counters is not counters       # a copy, per step
+
+
+def test_step_records_of_todays_program_hold_no_counters():
+    from repro.serving.scheduler import ScheduledAction
+
+    steps = _recorded(lambda live: ScheduledAction("decode", "full", None,
+                                                   live))
+    assert [s.counters for s in steps] == [{}] * len(steps)

@@ -1,7 +1,14 @@
 """A configuration file as the program's ``ModelConfig``: the qwen file
 maps as it always did, what the program cannot honour is refused by
 name before any weight, cache or gateway is made, and a served layout
-that differs from the file's widths stops the run."""
+that differs from the file's widths stops the run.
+
+The refusals are held under both mappings the harness can meet
+(fixture ``mapping``): ``bench``, no program mapping, so
+``bench/configmap.py`` maps; and ``program``, a stub program mapping
+that serves what ``configmap`` refuses of DeepSeek-V2-Lite (a leading
+dense layer, YaRN, an expert share) and refuses the rest with its own
+``ValueError`` subclass."""
 import copy
 import json
 import time
@@ -40,6 +47,40 @@ DEEPSEEK_V2_LITE = {
 }
 
 
+class StubRefusal(ValueError):
+    """The stub program mapping's own refusal: a ``ValueError`` with
+    ``keys``, as the program's contract states."""
+
+    def __init__(self, keys):
+        self.keys = list(keys)
+        super().__init__("the stub cannot honour " + ", ".join(self.keys))
+
+
+def stub_program_mapping(cfg):
+    """A program mapping that honours ``first_k_dense_replace``, YaRN
+    ``rope_scaling`` and an expert share under ``published`` (mapping the
+    file as if it stated none of them), and refuses whatever else
+    ``configmap`` refuses, with its own exception type."""
+    rest = {k: v for k, v in cfg.items() if k != "published"}
+    if "first_k_dense_replace" in rest:
+        rest["first_k_dense_replace"] = 0
+    if (rest.get("rope_scaling") or {}).get("type") == "yarn":
+        rest["rope_scaling"] = None
+    try:
+        return configmap.model_config(rest)
+    except configmap.UnsupportedConfig as e:
+        raise StubRefusal(e.keys) from None
+
+
+@pytest.fixture(params=["bench", "program"])
+def mapping(request, monkeypatch):
+    """Which mapping serves a file: none of the program's (``bench``), or
+    the stub program mapping (``program``)."""
+    stub = stub_program_mapping if request.param == "program" else None
+    monkeypatch.setattr(harness, "program_mapping", lambda: stub)
+    return request.param
+
+
 def test_qwen_file_maps_to_the_config_it_always_served():
     mapped = harness.model_config(QWEN)
     from repro.configs.base import ModelConfig
@@ -53,8 +94,15 @@ def test_qwen_file_maps_to_the_config_it_always_served():
         source="https://huggingface.co/Qwen/Qwen2.5-3B/blob/main/config.json")
 
 
+# keys no mapping honours: a mechanism unknown to the program, a sliding
+# window that is on, an activation no block of the program has
+UNHONOURED = [("use_sliding_window", True), ("hidden_act", "gelu"),
+              ("an_unknown_key", 0)]
+
+
+@pytest.mark.parametrize("key,value", UNHONOURED)
 def test_published_deepseek_v2_lite_stops_set_up_before_any_array(
-        monkeypatch):
+        mapping, key, value, monkeypatch):
     def never(*_a, **_k):
         raise AssertionError("set-up went past the mapping")
 
@@ -65,15 +113,26 @@ def test_published_deepseek_v2_lite_stops_set_up_before_any_array(
     cfg, mix = MLA_MOE
     spec = {"cell": {"name": "dsv2", "chips": 1, "traffic": "x"},
             "config": dict(DEEPSEEK_V2_LITE, serving=cfg["serving"],
-                           check=cfg["check"])}
+                           check=cfg["check"], **{key: value})}
     t0 = time.perf_counter()
-    with pytest.raises(ValueError) as err:
+    with pytest.raises(harness.UnsupportedConfig) as err:
         harness.set_up("dsv2", 1, require_chip=False, spec=spec, mix=mix,
                        log=lambda s: None)
     assert time.perf_counter() - t0 < 10
-    msg = str(err.value)
-    assert "first_k_dense_replace" in msg and "rope_scaling" in msg
+    assert key in err.value.keys and key in str(err.value)
+    # configmap refuses the published file's own two keys beside it; the
+    # program's mapping serves them
+    rest = {"bench": ["first_k_dense_replace", "rope_scaling"],
+            "program": []}[mapping]
+    assert sorted(err.value.keys) == sorted({key, *rest})
+
+
+def test_configmap_names_the_published_deepseek_v2_lite_keys_it_lacks():
+    with pytest.raises(configmap.UnsupportedConfig) as err:
+        configmap.model_config(DEEPSEEK_V2_LITE)
     assert err.value.keys == ["first_k_dense_replace", "rope_scaling"]
+    assert "first_k_dense_replace" in str(err.value)
+    assert "rope_scaling" in str(err.value)
 
 
 def test_deepseek_v2_lite_without_them_maps_at_published_widths():
@@ -109,14 +168,30 @@ def test_each_key_the_program_cannot_honour_is_named(key, value):
     assert err.value.keys == [key]
 
 
-@pytest.mark.parametrize("key,value", [
-    ("use_sliding_window", True), ("hidden_act", "gelu"),
-    ("an_unknown_key", 0)])
-def test_dense_file_refuses_what_the_program_cannot_honour(key, value):
+@pytest.mark.parametrize("key,value", UNHONOURED)
+def test_dense_file_refuses_what_the_program_cannot_honour(mapping, key,
+                                                           value):
     cfg = dict(QWEN, **{key: value})
-    with pytest.raises(configmap.UnsupportedConfig) as err:
+    with pytest.raises(harness.UnsupportedConfig) as err:
         harness.model_config(cfg)
     assert key in err.value.keys and key in str(err.value)
+    if mapping == "program":
+        assert isinstance(err.value.__cause__, StubRefusal)
+
+
+def test_a_program_refusal_without_keys_keeps_its_message(monkeypatch):
+    def refuse(cfg):
+        raise ValueError("no YaRN beyond factor 32")
+
+    monkeypatch.setattr(harness, "program_mapping", lambda: refuse)
+    with pytest.raises(harness.UnsupportedConfig) as err:
+        harness.model_config(QWEN)
+    assert err.value.keys == []
+    assert "no YaRN beyond factor 32" in str(err.value)
+
+
+def test_configmap_refuses_with_the_harness_type():
+    assert configmap.UnsupportedConfig is harness.UnsupportedConfig
 
 
 def test_toy_mla_moe_layout_holds_what_the_file_states():
@@ -204,6 +279,76 @@ def test_program_mapping_of_a_file_configmap_refuses_is_taken(monkeypatch):
     with pytest.raises(configmap.UnsupportedConfig):
         configmap.fields(DEEPSEEK_V2_LITE)
     assert harness.model_config(DEEPSEEK_V2_LITE).num_experts == 64
+
+
+# one chip's share of DeepSeek-V2-Lite under 4-way expert parallelism:
+# 16 of the 64 routed experts, every layer, the whole vocabulary
+DSV2_SHARE = dict(DEEPSEEK_V2_LITE, name="dsv2lite-share", n_routed_experts=16,
+                  published={"n_routed_experts": 64},
+                  reduced=["n_routed_experts"])
+
+
+def _share_layout(router=64, lead=True):
+    """The share's served layout as the program would hold it (shapes
+    only): layer 0 dense and unstacked, 26 expert layers stacked."""
+    import jax
+    import jax.numpy as jnp
+
+    d, h, r, vocab = 2048, 16, 512, 102400
+    mla = {"norm1/norm_scale": (d,), "norm2/norm_scale": (d,),
+           "mixer/ckv_norm": (r,), "mixer/wq/codes": (d, h * 192),
+           "mixer/w_dkv/codes": (d, r + 64), "mixer/w_ukv/codes": (r, h * 256),
+           "mixer/wo/codes": (h * 128, d)}
+    dense = {"ffn/w_up/codes": (d, 10944), "ffn/w_gate/codes": (d, 10944),
+             "ffn/w_down/codes": (10944, d)}
+    moe = {"ffn/experts/w_up/codes": (16, d, 1408),
+           "ffn/experts/w_gate/codes": (16, d, 1408),
+           "ffn/experts/w_down/codes": (16, 1408, d),
+           "ffn/router": (d, router), "ffn/shared/w_up/codes": (d, 2816),
+           "ffn/shared/w_gate/codes": (d, 2816),
+           "ffn/shared/w_down/codes": (2816, d)}
+    shapes = {"embed/tok": (vocab, d), "lm_head": (d, vocab),
+              "final_norm/norm_scale": (d,)}
+    if lead:
+        shapes.update({f"lead/{k}": v for k, v in {**mla, **dense}.items()})
+    shapes.update({f"units/b0/{k}": (26, *v)
+                   for k, v in {**mla, **moe}.items()})
+    return {k: jax.ShapeDtypeStruct(v, jnp.int8 if k.endswith("/codes")
+                                    else jnp.bfloat16)
+            for k, v in shapes.items()}
+
+
+class _Built(Exception):
+    """Set-up reached ``weights.build``."""
+
+
+@pytest.mark.parametrize("layout,named", [
+    ({}, None), ({"router": 16}, "/ffn/router"), ({"lead": False}, "/ffn/w_up")],
+    ids=["as-served", "router-16-wide", "lead-layer-missing"])
+def test_program_mapping_takes_a_deepseek_v2_lite_share(layout, named,
+                                                        monkeypatch):
+    monkeypatch.setattr(harness, "program_mapping",
+                        lambda: stub_program_mapping)
+    monkeypatch.setattr(harness, "served_layout",
+                        lambda mcfg: _share_layout(**layout))
+
+    def build(*_a, **_k):
+        raise _Built
+
+    monkeypatch.setattr(weights, "build", build)
+    cfg, mix = MLA_MOE
+    spec = {"cell": {"name": "dsv2", "chips": 1, "traffic": "x"},
+            "config": dict(DSV2_SHARE, serving=cfg["serving"],
+                           check=cfg["check"])}
+    err = _Built if named is None else widths.LayoutMismatch
+    with pytest.raises(err, match=named):
+        harness.set_up("dsv2", 1, require_chip=False, spec=spec, mix=mix,
+                       compile_cache=False, log=lambda s: None)
+    # configmap alone refuses the share by its three keys
+    with pytest.raises(configmap.UnsupportedConfig) as refused:
+        configmap.model_config(DSV2_SHARE)
+    assert refused.value.keys == ["first_k_dense_replace", "published",
+                                  "rope_scaling"]
 
 
 @pytest.mark.parametrize("path", sorted(

@@ -1,10 +1,16 @@
-"""``bench/run.py`` refuses to run without an accelerator, and without the
-program beside it, printing no result either way."""
+"""``bench/run.py`` refuses to run without an accelerator, without the
+program beside it, and on a configuration the program cannot serve,
+printing no result in each case."""
 import os
 import shutil
 import subprocess
 import sys
 from pathlib import Path
+
+import pytest
+
+from bench import harness, widths
+from bench import run as run_cli
 
 ROOT = Path(__file__).resolve().parents[2]
 ARGS = ["--workload", "qwen3b-chat-3tier", "--seed", "1", "--seconds", "1",
@@ -32,3 +38,23 @@ def test_exits_nonzero_with_only_the_benchmark_files(tmp_path):
     p = _run(tmp_path)
     assert p.returncode != 0
     assert p.stdout.strip() == ""
+
+
+@pytest.mark.parametrize("refusal", [
+    harness.UnsupportedConfig("configuration 'x': the program cannot honour "
+                              "rope_scaling", ["rope_scaling"]),
+    harness.MappingMismatch("configuration 'x': the program maps rope_theta "
+                            "to 10000.0, bench/configmap.py to 1000000.0"),
+    widths.LayoutMismatch("configuration 'x': layers: served 26, the file "
+                          "states 27"),
+], ids=lambda e: type(e).__name__)
+def test_a_configuration_it_cannot_serve_exits_1_with_one_line(
+        refusal, monkeypatch, capsys):
+    def run_cell(*_a, **_k):
+        raise refusal
+
+    monkeypatch.setattr(harness, "run_cell", run_cell)
+    assert run_cli.main(ARGS) == 1
+    out, err = capsys.readouterr()
+    assert out == ""
+    assert err == f"bench: {refusal}\n"
